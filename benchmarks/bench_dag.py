@@ -244,7 +244,8 @@ def run():
     )
     rows.append(
         ("dag_stage_queue_kernel", kern_s * 1e6,
-         f"interpret_on_cpu;scan/kernel={scan_s / max(kern_s, 1e-9):.2f}x")
+         f"platform={jax.devices()[0].platform};"
+         f"scan/kernel={scan_s / max(kern_s, 1e-9):.2f}x")
     )
 
     # one-cell rollout for the artifact's stage-level detail

@@ -6,10 +6,8 @@ from __future__ import annotations
 
 
 def run():
-    try:
-        from repro.launch import roofline
-    except Exception as e:  # pragma: no cover
-        return [("roofline", 0.0, f"unavailable:{e}")]
+    from repro.launch import roofline
+
     rows = []
     cells = roofline.load_all()
     if not cells:

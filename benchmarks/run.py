@@ -13,6 +13,8 @@ import sys
 import time
 import traceback
 
+from repro.launch.compile_cache import enable_compile_cache
+
 from . import (
     bench_dag,
     bench_fig3_fig5,
@@ -97,6 +99,7 @@ def main() -> None:
     ap = argparse.ArgumentParser()
     ap.add_argument("--only", nargs="*", default=None, choices=list(BENCHES))
     args = ap.parse_args()
+    enable_compile_cache()
     names = args.only or list(BENCHES)
     print("name,us_per_call,derived")
     failed = 0

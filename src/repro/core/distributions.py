@@ -15,6 +15,7 @@ Parameters are stored as Python floats (static under jit closures).
 from __future__ import annotations
 
 import dataclasses
+import math
 from typing import Tuple
 
 import jax
@@ -27,8 +28,20 @@ __all__ = [
     "Uniform",
     "Weibull",
     "Empirical",
+    "quantile_draws",
     "upper_end_point",
 ]
+
+
+def quantile_draws(key, quantile, shape):
+    """`quantile(jax.random.uniform(key, shape))` with the same values,
+    since threefry numbers the draws in row-major order, but transformed
+    with the last axis leading.  For a TPU, an empirical quantile's gather
+    into an array whose last axis is short or not a multiple of 128 (r+1
+    replicas, a task count such as 1026) takes the compiler 40 s and more;
+    gathering into the transposed array takes about two."""
+    u = jax.random.uniform(key, (math.prod(shape),)).reshape(-1, shape[-1])
+    return quantile(u.T).T.reshape(shape)
 
 
 class Distribution:

@@ -25,7 +25,7 @@ from functools import partial
 import jax
 import jax.numpy as jnp
 
-from .distributions import Distribution
+from .distributions import Distribution, quantile_draws
 from .policy import (
     MODE_QUANTILE,
     MultiForkPolicy,
@@ -127,8 +127,8 @@ def policy_draws(key, quantile, shape, n: int, r_cap: int, n_stages: int = 1):
     the pre-algebra fused path.
     """
     kx, ky = jax.random.split(key)
-    x = quantile(jax.random.uniform(kx, shape + (n,)))
-    fresh = quantile(jax.random.uniform(ky, shape + (n_stages, n, r_cap)))
+    x = quantile_draws(kx, quantile, shape + (n,))
+    fresh = quantile_draws(ky, quantile, shape + (n_stages, n, r_cap))
     return x, fresh
 
 

@@ -20,6 +20,7 @@ from .rollout import (  # noqa: F401
     DagRolloutResult,
     dag_frontier,
     dag_rollout,
+    lower_dag_frontier,
     vector_label,
 )
 from .search import (  # noqa: F401
@@ -52,6 +53,7 @@ __all__ = [
     "dag_frontier",
     "dag_rollout",
     "exhaustive_search",
+    "lower_dag_frontier",
     "poisson_arrivals",
     "run_dag_fleet",
     "uniform_vectors",

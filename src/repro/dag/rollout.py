@@ -67,7 +67,10 @@ from repro.fleet.vector import (
 
 from .graph import JobDAG
 
-__all__ = ["DagRolloutResult", "dag_frontier", "dag_rollout", "vector_label"]
+__all__ = [
+    "DagRolloutResult", "dag_frontier", "dag_rollout", "lower_dag_frontier",
+    "vector_label",
+]
 
 
 def vector_label(policies: Sequence[SingleForkPolicy], dag: Optional[JobDAG] = None) -> str:
@@ -386,31 +389,13 @@ def _resolve_r_caps(dag, cell_vectors, r_caps):
     return r_caps
 
 
-def _eval_dag_cells(
-    dag: JobDAG,
-    cell_vectors,
-    cell_lams,
-    n_jobs: int,
-    m_trials: int,
-    key,
-    kernel: bool,
-    r_caps,
-    pad_cells: bool,
-    tail="exact",
-    cell_qs=None,
-    attempts=None,
-):
-    """Shared engine behind `dag_frontier` (and the joint searches): one
-    stats dict per (policy-vector, λ) cell from a single fused dispatch.
-    `tail` follows the fleet `_eval_cells` convention: "exact" ships the
-    sojourn matrices, "hist" / a `repro.obs.HistSpec` ships in-program
-    bincounts and adds cost_p50/cost_p99/cost_p999 to every row.
-    `cell_qs` (one per cell, static draw width `attempts`) runs every stage
-    under the geometric-retry transform; None keeps the historical
-    bit-identical programs."""
+def _dag_cells_call(dag, cell_vectors, cell_lams, n_jobs, m_trials, key, kernel,
+                    r_caps, pad_cells, tail, cell_qs, attempts):
+    """Check one grid of already validated policy vectors and lower it onto the
+    fused program: returns `_dag_stats_jit`'s positional and keyword
+    arguments and the hist spec its rows are read back with."""
     if not cell_vectors:
         raise ValueError("need at least one candidate policy vector")
-    cell_vectors = [dag.validate_policy_vector(v) for v in cell_vectors]
     if any(lam <= 0 for lam in cell_lams):
         raise ValueError("arrival rate lam must be > 0")
     if key is None:
@@ -437,7 +422,7 @@ def _eval_dag_cells(
     # one rounding contract), algebra grids carry the general param tensors
     ks, rs, keeps, gen_kwargs = _stage_pol_args(_stage_lowerings(dag, vecs))
 
-    from repro.obs.device import HistSpec, DEFAULT_HIST, sketch_from_device
+    from repro.obs.device import HistSpec, DEFAULT_HIST
 
     if tail == "exact":
         hist = None
@@ -448,11 +433,42 @@ def _eval_dag_cells(
     else:
         raise ValueError(f'tail must be "exact", "hist", or a HistSpec, got {tail!r}')
 
-    stats, payload = _dag_stats_jit(
-        key, xss, ks, rs, keeps,
-        jnp.asarray(lams), plan, sinks, n_jobs, m_trials, r_caps, kernel,
-        hist=hist, qs=qs_arg, attempts=attempts, **gen_kwargs,
+    args = (key, xss, ks, rs, keeps, jnp.asarray(lams), plan, sinks, n_jobs,
+            m_trials, r_caps, kernel)
+    return args, dict(hist=hist, qs=qs_arg, attempts=attempts, **gen_kwargs), hist
+
+
+def _eval_dag_cells(
+    dag: JobDAG,
+    cell_vectors,
+    cell_lams,
+    n_jobs: int,
+    m_trials: int,
+    key,
+    kernel: bool,
+    r_caps,
+    pad_cells: bool,
+    tail="exact",
+    cell_qs=None,
+    attempts=None,
+):
+    """Shared engine behind `dag_frontier` (and the joint searches): one
+    stats dict per (policy-vector, λ) cell from a single fused dispatch.
+    `tail` follows the fleet `_eval_cells` convention: "exact" ships the
+    sojourn matrices, "hist" / a `repro.obs.HistSpec` ships in-program
+    bincounts and adds cost_p50/cost_p99/cost_p999 to every row.
+    `cell_qs` (one per cell, static draw width `attempts`) runs every stage
+    under the geometric-retry transform; None keeps the historical
+    bit-identical programs."""
+    cell_vectors = [dag.validate_policy_vector(v) for v in cell_vectors]
+    args, kwargs, hist = _dag_cells_call(
+        dag, cell_vectors, cell_lams, n_jobs, m_trials, key, kernel, r_caps,
+        pad_cells, tail, cell_qs, attempts,
     )
+    n_cells = len(cell_vectors)
+    from repro.obs.device import sketch_from_device
+
+    stats, payload = _dag_stats_jit(*args, **kwargs)
     stats = np.asarray(stats)[:n_cells]
     if hist is None:
         soj = np.asarray(payload)[:n_cells]
@@ -560,6 +576,31 @@ def dag_frontier(
         dag, cell_vectors, cell_lams, n_jobs, m_trials, key, kernel, r_caps,
         pad_cells, tail=tail, cell_qs=cell_qs, attempts=attempts,
     )
+
+
+def lower_dag_frontier(
+    dag: JobDAG,
+    policy_vectors,
+    lams,
+    n_jobs: int,
+    m_trials: int = 32,
+    key=None,
+    kernel: bool = False,
+    r_caps=None,
+    pad_cells: bool = True,
+    tail="exact",
+):
+    """The fault-free device program `dag_frontier` runs for these
+    arguments, lowered but not run (a `jax.stages.Lowered`).  `.compile()`
+    gives its memory analysis and optimized HLO, and a later `dag_frontier`
+    call of the same shapes reuses that executable."""
+    lams = [float(lam) for lam in lams]
+    cell_vectors = [dag.validate_policy_vector(tuple(v)) for v in policy_vectors for _ in lams]
+    args, kwargs, _ = _dag_cells_call(
+        dag, cell_vectors, lams * len(policy_vectors), n_jobs, m_trials, key,
+        kernel, r_caps, pad_cells, tail, None, None,
+    )
+    return _dag_stats_jit.lower(*args, **kwargs)
 
 
 @dataclasses.dataclass

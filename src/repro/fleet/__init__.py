@@ -60,6 +60,7 @@ from . import vector  # noqa: F401
 from .vector import (  # noqa: F401
     fleet_rollout,
     frontier,
+    lower_frontier,
     policy_search,
     sweep,
     trace_kill_rollout,
@@ -101,6 +102,7 @@ __all__ = [
     "fleet_rollout",
     "frontier",
     "ks_statistic",
+    "lower_frontier",
     "piecewise_poisson_workload",
     "poisson_workload",
     "policy_search",

@@ -68,7 +68,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from repro.core.distributions import Distribution, Empirical
+from repro.core.distributions import Distribution, Empirical, quantile_draws
 from repro.core.policy import SingleForkPolicy, lower_policies, num_stragglers
 from repro.core.simulate import lowered_policy_eval, policy_draws, single_fork_batch
 
@@ -85,6 +85,7 @@ __all__ = [
     "frontier",
     "kw_queue",
     "lindley",
+    "lower_frontier",
     "masked_single_fork",
     "policy_search",
     "retry_draws",
@@ -515,7 +516,7 @@ def retry_draws(key, quantile, shape, attempts: int):
     over cells compares the same failure fates at different q thresholds.
     """
     ku, kv = jax.random.split(key)
-    x = quantile(jax.random.uniform(ku, shape + (attempts,)))
+    x = quantile_draws(ku, quantile, shape + (attempts,))
     v = jax.random.uniform(kv, shape + (attempts - 1,))
     return x, v
 
@@ -546,8 +547,8 @@ def fork_draws(key, quantile, shape, n: int, r_cap: int):
     fused engine.  Returns (x_sorted: shape+(n,), fresh: shape+(n, r_cap)).
     """
     kx, ky = jax.random.split(key)
-    x_sorted = jnp.sort(quantile(jax.random.uniform(kx, shape + (n,))), axis=-1)
-    fresh = quantile(jax.random.uniform(ky, shape + (n, r_cap)))
+    x_sorted = jnp.sort(quantile_draws(kx, quantile, shape + (n,)), axis=-1)
+    fresh = quantile_draws(ky, quantile, shape + (n, r_cap))
     return x_sorted, fresh
 
 
@@ -835,36 +836,14 @@ def cell_bucket(n_cells: int) -> int:
     return b
 
 
-def _eval_cells(
-    dist_or_samples,
-    cell_policies: Sequence,
-    cell_lams: Sequence[float],
-    n: int,
-    n_jobs: int,
-    m_trials: int,
-    key,
-    c: Optional[int],
-    classes: Optional[Sequence[MachineClass]],
-    kernel: bool,
-    r_cap: Optional[int],
-    pad_cells: bool,
-    tail="exact",
-    cell_qs: Optional[Sequence[float]] = None,
-    attempts: Optional[int] = None,
-) -> list[dict]:
-    """Shared engine behind `frontier` and `policy_search`: one stats dict
-    per (policy, λ) cell, computed by a single `_frontier_jit` dispatch.
-    `cell_qs` (one per cell, with the static draw width `attempts`) routes
-    the grid through `_frontier_faulty_jit` instead — the q failure law via
-    the geometric-retry transform; cell_qs=None never touches the faulty
-    program, preserving the historical engine's bit-identity.
-
-    `tail` selects how the percentile keys are computed: "exact" pulls the
-    full sojourn matrices host-side (np.partition semantics, bit-exact);
-    "hist" (or a `repro.obs.HistSpec`) keeps samples on device and ships
-    γ-bucket bincounts — p50/p99/p999 then carry the sketch's relative-
-    accuracy guarantee, the off-device transfer is fixed-size per cell,
-    and rows additionally get cost_p50/cost_p99/cost_p999."""
+def _cells_call(
+    dist_or_samples, cell_policies, cell_lams, n, n_jobs, m_trials, key, c,
+    classes, kernel, r_cap, pad_cells, tail, cell_qs, attempts,
+):
+    """Validate one grid and lower it onto the fused program: returns the
+    jitted program `_eval_cells` dispatches, its positional arguments, its
+    `hist` keyword (the tail spec the rows are read back with) and the
+    class names — None for a grid without slot classes."""
     if not cell_policies:
         raise ValueError("need at least one candidate policy")
     if any(lam <= 0 for lam in cell_lams):
@@ -903,7 +882,7 @@ def _eval_cells(
         qs = [float(q) for q in cell_qs]
         qs.extend([qs[0]] * (n_padded - n_cells))
 
-    from repro.obs.device import HistSpec, DEFAULT_HIST, sketch_from_device
+    from repro.obs.device import HistSpec, DEFAULT_HIST
 
     if tail == "exact":
         hist = None
@@ -914,19 +893,6 @@ def _eval_cells(
     else:
         raise ValueError(f'tail must be "exact", "hist", or a HistSpec, got {tail!r}')
 
-    from repro.obs.profile import jit_cache_size
-    from repro.obs.trace import PID_PROFILER, get_recorder
-
-    rec = get_recorder()
-    # re-trace detection (obs.retrace): the padded-grid contract promises
-    # that re-plans inside one geometry never recompile — observe it by
-    # watching the jit cache across the dispatch
-    _dispatch_fn = _frontier_jit if cell_qs is None else _frontier_faulty_jit
-    _cache_before = jit_cache_size(_dispatch_fn)
-    if rec.enabled:
-        import time as _time
-
-        t0 = _time.perf_counter()
     # grids entirely in the single-stage-quantile/full-width domain take the
     # historical program (modes=None → bit-identical HLO to the pre-algebra
     # engine); anything else takes the general lowered evaluator.  Either
@@ -943,29 +909,82 @@ def _eval_cells(
             None, jnp.asarray(lowered.k[:, 0]), None,
             jnp.asarray(lowered.r[:, 0]), jnp.asarray(lowered.keep[:, 0]), None,
         )
+    names = names if slot is not None else None
     if cell_qs is None:
-        stats, payload = _frontier_jit(
-            key, xs, *pol_args,
-            jnp.array(lams), speeds, slot_class, class_slots,
-            dist, n, n_jobs, m_trials, r_cap, lowered.n_stages, kernel, hist=hist,
+        args = (
+            key, xs, *pol_args, jnp.array(lams), speeds, slot_class, class_slots,
+            dist, n, n_jobs, m_trials, r_cap, lowered.n_stages, kernel,
         )
-    else:
-        stats, payload = _frontier_faulty_jit(
-            key, xs, *pol_args,
-            jnp.array(lams), jnp.array(qs), speeds, slot_class, class_slots,
-            dist, n, n_jobs, m_trials, r_cap, lowered.n_stages, attempts,
-            kernel, hist=hist,
-        )
+        return _frontier_jit, args, hist, names
+    args = (
+        key, xs, *pol_args, jnp.array(lams), jnp.array(qs), speeds, slot_class,
+        class_slots, dist, n, n_jobs, m_trials, r_cap, lowered.n_stages, attempts,
+        kernel,
+    )
+    return _frontier_faulty_jit, args, hist, names
+
+
+def _eval_cells(
+    dist_or_samples,
+    cell_policies: Sequence,
+    cell_lams: Sequence[float],
+    n: int,
+    n_jobs: int,
+    m_trials: int,
+    key,
+    c: Optional[int],
+    classes: Optional[Sequence[MachineClass]],
+    kernel: bool,
+    r_cap: Optional[int],
+    pad_cells: bool,
+    tail="exact",
+    cell_qs: Optional[Sequence[float]] = None,
+    attempts: Optional[int] = None,
+) -> list[dict]:
+    """Shared engine behind `frontier` and `policy_search`: one stats dict
+    per (policy, λ) cell, computed by a single `_frontier_jit` dispatch.
+    `cell_qs` (one per cell, with the static draw width `attempts`) routes
+    the grid through `_frontier_faulty_jit` instead — the q failure law via
+    the geometric-retry transform; cell_qs=None never touches the faulty
+    program, preserving the historical engine's bit-identity.
+
+    `tail` selects how the percentile keys are computed: "exact" pulls the
+    full sojourn matrices host-side (np.partition semantics, bit-exact);
+    "hist" (or a `repro.obs.HistSpec`) keeps samples on device and ships
+    γ-bucket bincounts — p50/p99/p999 then carry the sketch's relative-
+    accuracy guarantee, the off-device transfer is fixed-size per cell,
+    and rows additionally get cost_p50/cost_p99/cost_p999."""
+    fn, args, hist, names = _cells_call(
+        dist_or_samples, cell_policies, cell_lams, n, n_jobs, m_trials, key, c,
+        classes, kernel, r_cap, pad_cells, tail, cell_qs, attempts,
+    )
+    n_cells = len(cell_policies)
+    from repro.obs.device import sketch_from_device
+    from repro.obs.profile import jit_cache_size
+    from repro.obs.trace import PID_PROFILER, get_recorder
+
+    rec = get_recorder()
+    # re-trace detection (obs.retrace): the padded-grid contract promises
+    # that re-plans inside one geometry never recompile — observe it by
+    # watching the jit cache across the dispatch
+    _cache_before = jit_cache_size(fn)
+    if rec.enabled:
+        import time as _time
+
+        t0 = _time.perf_counter()
+    stats, payload = fn(*args, hist=hist)
     if rec.enabled:
         jax.block_until_ready((stats, payload))
         rec.span(
             "frontier_dispatch", "engine", t0, _time.perf_counter() - t0,
             pid=PID_PROFILER,
-            args=dict(cells=n_cells, padded=n_padded, m_trials=m_trials,
+            args=dict(cells=n_cells,
+                      padded=cell_bucket(n_cells) if pad_cells else n_cells,
+                      m_trials=m_trials,
                       n_jobs=n_jobs, tail="exact" if hist is None else "hist"),
         )
         rec.count("frontier.cells", n_cells)
-        _cache_after = jit_cache_size(_dispatch_fn)
+        _cache_after = jit_cache_size(fn)
         if _cache_before is not None and _cache_after is not None:
             delta = _cache_after - _cache_before
             if delta > 0:
@@ -1007,7 +1026,7 @@ def _eval_cells(
                 float(cost_pcts[j, i]) for j in range(3)
             )
             d.update(cell_evt[i])
-        if slot is not None:  # mirror VectorFleetResult.summary(): per-class util
+        if names is not None:  # mirror VectorFleetResult.summary(): per-class util
             for name, u in zip(names, row[nk:]):
                 d[f"util_{name}"] = float(u)
         rows.append(d)
@@ -1134,6 +1153,34 @@ def frontier(
         c, classes, kernel, r_cap, pad_cells, tail=tail,
         cell_qs=cell_qs, attempts=attempts,
     )
+
+
+def lower_frontier(
+    dist_or_samples,
+    policies: Sequence,
+    lams,
+    n: int,
+    n_jobs: int,
+    m_trials: int = 32,
+    key=None,
+    c: Optional[int] = None,
+    classes: Optional[Sequence[MachineClass]] = None,
+    kernel: bool = False,
+    r_cap: Optional[int] = None,
+    pad_cells: bool = True,
+    tail="exact",
+):
+    """The fault-free device program `frontier` runs for these arguments,
+    lowered but not run (a `jax.stages.Lowered`).  `.compile()` gives its
+    memory analysis and optimized HLO, and a later `frontier` (or, at one
+    λ, `policy_search`) call of the same shapes reuses that executable."""
+    lams = [float(lam) for lam in lams]
+    fn, args, hist, _ = _cells_call(
+        dist_or_samples, [pol for pol in policies for _ in lams],
+        lams * len(policies), n, n_jobs, m_trials, key, c, classes, kernel,
+        r_cap, pad_cells, tail, None, None,
+    )
+    return fn.lower(*args, hist=hist)
 
 
 def sweep(
@@ -1312,8 +1359,7 @@ def trace_kill_rollout(
     k0, k1, k2 = jax.random.split(key, 3)
 
     # originals: (M, n) draws through the one true inverse-transform gather
-    u0 = jax.random.uniform(k0, (M, n))
-    x_sorted = jnp.sort(emp.quantile(u0), axis=1)
+    x_sorted = jnp.sort(quantile_draws(k0, emp.quantile, (M, n)), axis=1)
     if s == 0:  # baseline: no residual phase, nothing for the kernel to do
         T = x_sorted[:, -1].reshape(m_trials, n_jobs)
         C = (jnp.sum(x_sorted, axis=1) / n).reshape(m_trials, n_jobs)

@@ -21,7 +21,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels import CompilerParams as _CompilerParams
+from repro.kernels import run_kernel
 
 NEG_INF = -2.0**30
 
@@ -79,18 +79,9 @@ def _kernel(q_ref, k_ref, v_ref, o_ref, m_ref, l_ref, acc_ref, *, causal, block_
         o_ref[0, 0] = (acc_ref[...] / l[:, None]).astype(o_ref.dtype)
 
 
-@functools.partial(
-    jax.jit, static_argnames=("causal", "block_q", "block_k", "interpret")
-)
-def flash_attention(
-    q, k, v, *, causal: bool = True, block_q: int = 128, block_k: int = 128,
-    interpret: bool | None = None,
-):
+@functools.partial(jax.jit, static_argnames=("causal", "block_q", "block_k"))
+def flash_attention(q, k, v, *, causal: bool = True, block_q: int = 128, block_k: int = 128):
     """q,k,v: (B, S, H, D) with H already GQA-expanded.  Returns (B, S, H, D)."""
-    if interpret is None:
-        from repro.kernels import INTERPRET
-
-        interpret = INTERPRET
     B, Sq, H, D = q.shape
     Sk = k.shape[1]
     # (B,H,S,D) layout for tiling
@@ -116,7 +107,7 @@ def flash_attention(
         _kernel, causal=causal, block_q=block_q, block_k=block_k,
         scale=scale, kv_len=Sk,
     )
-    out = pl.pallas_call(
+    call = lambda *args, interpret: pl.pallas_call(  # noqa: E731
         kernel,
         grid=grid,
         in_specs=[
@@ -131,11 +122,12 @@ def flash_attention(
             pltpu.VMEM((block_q,), jnp.float32),
             pltpu.VMEM((block_q, D), jnp.float32),
         ],
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "parallel", "arbitrary"),
         ),
         interpret=interpret,
-    )(qt, kt, vt)
+    )(*args)
+    out = run_kernel(call, qt, kt, vt)
     if pad_q:
         out = out[:, :, :Sq]
     return out.transpose(0, 2, 1, 3)

@@ -3,25 +3,26 @@
 The G/G/c recursion start_j = max(arrival_j, free-time of the chosen slot)
 is inherently sequential over jobs, but a frontier evaluation runs
 (trials × grid-cells) *independent* queues — the fused `fleet.vector`
-engine flattens that batch into rows and this kernel tiles the rows across
-the Pallas grid.  Memory layout per grid step:
+engine flattens that batch into rows and this kernel tiles them across
+the Pallas grid.  Layout:
 
-  * the c-vector of slot free-times lives in registers/VMEM for a block of
-    `block_b` queues and never touches HBM (the whole point: the scan
-    version materializes an (n_jobs, c) carry trace through XLA's scan);
-  * arrivals/services stream in as (block_b, n_jobs) VMEM tiles, the four
-    outputs (start, finish, scaled service, serving slot) stream out the
-    same way;
-  * jobs advance with a `fori_loop` inside the kernel; slot selection is
-    branch-free min/where reductions over the lane axis (no gather/argmin,
-    so the body lowers through Mosaic as pure VPU ops).
+  * independent queues sit on the 128-wide lane axis and jobs on the
+    sublane axis: the wrapper transposes (n_queues, n_jobs) to
+    (n_jobs, n_queues), so the per-job read and write are whole rows of
+    a VMEM tile, taken 8 jobs (one f32 sublane tile) at a time at aligned
+    offsets — no lane-axis dynamic slice, which Mosaic cannot lower;
+  * the grid is (queue blocks, job blocks); job blocks run in order
+    ("arbitrary") and the (c, 128) tile of slot free-times lives in a
+    VMEM scratch carried across them, so n_jobs is not bounded by VMEM
+    and the free-time state never touches HBM;
+  * slot selection is branch-free min/where reductions over the c slots
+    (the sublane axis of the free-time tile): no gather, no argmin.
 
 Semantics are identical to `repro.fleet.vector.kw_queue` (the lax.scan
 reference): job j takes the lowest-indexed slot already idle at its
 arrival — slots are ordered fastest first — else the earliest-freeing
 slot (ties toward lower index); its service requirement stretches by the
-chosen slot's speed.  Oracle: kernels/ref.py::kw_queue_ref; interpret-mode
-fallback on CPU follows the `residual_sampler` pattern.
+chosen slot's speed.  Oracle: kernels/ref.py::kw_queue_ref.
 """
 
 from __future__ import annotations
@@ -31,87 +32,93 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
+
+from repro.kernels import run_kernel
+
+#: queues per grid step (the lane width)
+BLOCK_B = 128
+#: jobs per grid step, at most; a multiple of the 8-row f32 sublane tile
+BLOCK_J = 512
+_ROWS = 8
 
 
-def _kernel(a_ref, s_ref, sp_ref, start_ref, fin_ref, svc_ref, slot_ref, *, n_jobs, c):
-    a = a_ref[...]  # (block_b, n_jobs)
-    s = s_ref[...]
-    b = a.shape[0]
-    speeds = jnp.broadcast_to(sp_ref[...].reshape(1, c), (b, c))
-    lane = jax.lax.broadcasted_iota(jnp.int32, (b, c), 1)
-    big = jnp.int32(c)  # sentinel lane: "no idle slot"
+def _kernel(a_ref, s_ref, sp_ref, start_ref, fin_ref, svc_ref, slot_ref, free_ref, *, c):
+    @pl.when(pl.program_id(1) == 0)
+    def _init():
+        free_ref[...] = jnp.zeros_like(free_ref)
 
-    def body(j, carry):
-        free, starts, fins, svcs, slots = carry
-        aj = jax.lax.dynamic_slice(a, (0, j), (b, 1))
-        sj = jax.lax.dynamic_slice(s, (0, j), (b, 1))
-        idle = free <= aj
-        first_idle = jnp.min(jnp.where(idle, lane, big), axis=1, keepdims=True)
-        min_free = jnp.min(free, axis=1, keepdims=True)
-        soonest = jnp.min(jnp.where(free == min_free, lane, big), axis=1, keepdims=True)
-        slot = jnp.where(first_idle < big, first_idle, soonest)
-        hit = lane == slot
-        free_sel = jnp.sum(jnp.where(hit, free, 0.0), axis=1, keepdims=True)
-        speed_sel = jnp.sum(jnp.where(hit, speeds, 0.0), axis=1, keepdims=True)
-        start = jnp.maximum(aj, free_sel)
-        svc = sj / speed_sel
-        finish = start + svc
-        free = jnp.where(hit, finish, free)
-        starts = jax.lax.dynamic_update_slice(starts, start, (0, j))
-        fins = jax.lax.dynamic_update_slice(fins, finish, (0, j))
-        svcs = jax.lax.dynamic_update_slice(svcs, svc, (0, j))
-        slots = jax.lax.dynamic_update_slice(slots, slot, (0, j))
-        return free, starts, fins, svcs, slots
+    bb = a_ref.shape[1]
+    speeds = sp_ref[...]  # (c, bb)
+    slot_id = jax.lax.broadcasted_iota(jnp.int32, (c, bb), 0)
+    row_id = jax.lax.broadcasted_iota(jnp.int32, (_ROWS, bb), 0)
+    none = jnp.int32(c)  # sentinel slot: "no idle slot"
 
-    dt = a.dtype
-    init = (
-        jnp.zeros((b, c), dt),
-        jnp.zeros((b, n_jobs), dt),
-        jnp.zeros((b, n_jobs), dt),
-        jnp.zeros((b, n_jobs), dt),
-        jnp.zeros((b, n_jobs), jnp.int32),
-    )
-    _, starts, fins, svcs, slots = jax.lax.fori_loop(0, n_jobs, body, init)
-    start_ref[...] = starts
-    fin_ref[...] = fins
-    svc_ref[...] = svcs
-    slot_ref[...] = slots
+    def group(g, free):
+        rows = pl.ds(pl.multiple_of(g * _ROWS, _ROWS), _ROWS)
+        a8 = a_ref[rows, :]
+        s8 = s_ref[rows, :]
+        st8 = fi8 = sv8 = jnp.zeros((_ROWS, bb), a8.dtype)
+        sl8 = jnp.zeros((_ROWS, bb), jnp.int32)
+        for r in range(_ROWS):
+            aj = a8[r : r + 1, :]
+            sj = s8[r : r + 1, :]
+            first_idle = jnp.min(jnp.where(free <= aj, slot_id, none), axis=0, keepdims=True)
+            min_free = jnp.min(free, axis=0, keepdims=True)
+            soonest = jnp.min(jnp.where(free == min_free, slot_id, none), axis=0, keepdims=True)
+            slot = jnp.where(first_idle < none, first_idle, soonest)
+            hit = slot_id == slot
+            free_sel = jnp.sum(jnp.where(hit, free, 0.0), axis=0, keepdims=True)
+            speed_sel = jnp.sum(jnp.where(hit, speeds, 0.0), axis=0, keepdims=True)
+            start = jnp.maximum(aj, free_sel)
+            svc = sj / speed_sel
+            finish = start + svc
+            free = jnp.where(hit, finish, free)
+            at = row_id == r
+            st8 = jnp.where(at, start, st8)
+            fi8 = jnp.where(at, finish, fi8)
+            sv8 = jnp.where(at, svc, sv8)
+            sl8 = jnp.where(at, slot, sl8)
+        start_ref[rows, :] = st8
+        fin_ref[rows, :] = fi8
+        svc_ref[rows, :] = sv8
+        slot_ref[rows, :] = sl8
+        return free
+
+    free_ref[...] = jax.lax.fori_loop(0, a_ref.shape[0] // _ROWS, group, free_ref[...])
 
 
-@functools.partial(jax.jit, static_argnames=("block_b", "interpret"))
-def kw_queue(arrivals, services, speeds, *, block_b: int = 8, interpret: bool | None = None):
+def _call(a, s, sp, *, block_j, interpret):
+    (Jp, Bp), c = a.shape, sp.shape[0]
+    tile = pl.BlockSpec((block_j, BLOCK_B), lambda i, j: (j, i))
+    return pl.pallas_call(
+        functools.partial(_kernel, c=c),
+        grid=(Bp // BLOCK_B, Jp // block_j),
+        in_specs=[tile, tile, pl.BlockSpec((c, BLOCK_B), lambda i, j: (0, 0))],
+        out_specs=[tile] * 4,
+        out_shape=[jax.ShapeDtypeStruct((Jp, Bp), a.dtype)] * 3
+        + [jax.ShapeDtypeStruct((Jp, Bp), jnp.int32)],
+        scratch_shapes=[pltpu.VMEM((c, BLOCK_B), a.dtype)],
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "arbitrary"),
+        ),
+        interpret=interpret,
+    )(a, s, sp)
+
+
+@jax.jit
+def kw_queue(arrivals, services, speeds):
     """arrivals, services: (n_queues, n_jobs) independent FIFO queues;
     speeds: (c,) per-slot speed multipliers, sorted descending.
     Returns (starts, finishes, scaled_services, slots), each (n_queues, n_jobs)."""
-    if interpret is None:
-        from repro.kernels import INTERPRET
-
-        interpret = INTERPRET
     B, J = arrivals.shape
     c = speeds.shape[0]
-    pad_b = (-B) % block_b
-    if pad_b:
-        arrivals = jnp.pad(arrivals, ((0, pad_b), (0, 0)))
-        services = jnp.pad(services, ((0, pad_b), (0, 0)), constant_values=1.0)
-    Bp = arrivals.shape[0]
-    grid = (Bp // block_b,)
-    kernel = functools.partial(_kernel, n_jobs=J, c=c)
-    fdt = arrivals.dtype
-    starts, fins, svcs, slots = pl.pallas_call(
-        kernel,
-        grid=grid,
-        in_specs=[
-            pl.BlockSpec((block_b, J), lambda i: (i, 0)),
-            pl.BlockSpec((block_b, J), lambda i: (i, 0)),
-            pl.BlockSpec((c,), lambda i: (0,)),
-        ],
-        out_specs=[pl.BlockSpec((block_b, J), lambda i: (i, 0))] * 4,
-        out_shape=[
-            jax.ShapeDtypeStruct((Bp, J), fdt),
-            jax.ShapeDtypeStruct((Bp, J), fdt),
-            jax.ShapeDtypeStruct((Bp, J), fdt),
-            jax.ShapeDtypeStruct((Bp, J), jnp.int32),
-        ],
-        interpret=interpret,
-    )(arrivals, services, speeds)
-    return starts[:B], fins[:B], svcs[:B], slots[:B]
+    block_j = min(BLOCK_J, -(-J // _ROWS) * _ROWS)
+    pad = ((0, (-J) % block_j), (0, (-B) % BLOCK_B))
+    # padded jobs queue after every real one and padded queues are sliced
+    # off, so neither changes a real output; unit services keep them finite
+    a = jnp.pad(arrivals.T, pad)
+    s = jnp.pad(services.T, pad, constant_values=1.0)
+    sp = jnp.broadcast_to(speeds.astype(a.dtype)[:, None], (c, BLOCK_B))
+    outs = run_kernel(functools.partial(_call, block_j=block_j), a, s, sp)
+    return tuple(z[:J, :B].T for z in outs)

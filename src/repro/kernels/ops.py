@@ -1,8 +1,8 @@
 """jit'd public wrappers for the Pallas kernels.
 
-On non-TPU backends the kernels execute in interpret mode (the kernel body
-runs as traced jnp on CPU), which is how this container validates them; on
-TPU they compile through Mosaic.
+A kernel compiles through Mosaic in a program lowered for a TPU and runs
+in interpret mode (the kernel body as traced jnp) in one lowered for any
+other platform; `repro.kernels.run_kernel` makes that choice per program.
 """
 
 from .flash_attention import flash_attention  # noqa: F401

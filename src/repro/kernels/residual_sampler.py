@@ -6,9 +6,12 @@ reduces max_j Y_j (the latency tail term) and sum_j Y_j (the cost term).
 Empirical inverse-transform sampling is an integer gather:
 F̂_X^{-1}(u) = xs[ceil(u·n)-1] with xs the sorted trace.
 
-The kernel fuses gather + min-over-replicas + max/sum reductions per trial
-block: uniforms stream through VMEM, the sorted trace stays VMEM-resident
-(one tile, n <= a few thousand in every trace the paper uses).
+The gather runs in XLA (an arbitrary gather from a trace of ~1000 values
+does not lower through Mosaic); the kernel fuses what follows it: the min
+over replicas and the max / sum reductions per trial.  Trials sit on the
+128-wide lane axis, stragglers on the sublane axis and replicas on the
+leading axis of each (k, s, 128) VMEM tile, so every reduction is
+elementwise or over sublanes and each output block is a (1, 128) row.
 
 Used by the π_kill path of the vectorized estimator (eq. (7):
 F̄_Y = F̄_X^{r+1} — i.e. Y is exactly a min of r+1 fresh draws); the
@@ -18,54 +21,44 @@ general path (π_keep) goes through the tabulated-cdf route in
 
 from __future__ import annotations
 
-import functools
-
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
+from repro.kernels import run_kernel
 
-def _kernel(u_ref, xs_ref, mx_ref, sm_ref, *, n):
-    u = u_ref[...]  # (block_m, s, k)
-    xs = xs_ref[...]  # (n,)
-    idx = jnp.clip(jnp.ceil(u * n).astype(jnp.int32) - 1, 0, n - 1)
-    draws = xs[idx]  # gather: (block_m, s, k)
-    y = jnp.min(draws, axis=-1)  # min over r+1 replicas
-    mx_ref[...] = jnp.max(y, axis=-1)  # (block_m,)
-    sm_ref[...] = jnp.sum(y, axis=-1)
+#: trials per grid step (the lane width)
+BLOCK_M = 128
 
 
-@functools.partial(jax.jit, static_argnames=("block_m", "interpret"))
-def residual_sample(u, xs, *, block_m: int = 8, interpret: bool | None = None):
+def _kernel(d_ref, mx_ref, sm_ref):
+    y = jnp.min(d_ref[...], axis=0)  # (s, block_m): min over r+1 replicas
+    mx_ref[...] = jnp.max(y, axis=0, keepdims=True)
+    sm_ref[...] = jnp.sum(y, axis=0, keepdims=True)
+
+
+def _call(draws, *, interpret):
+    k, s, mp = draws.shape
+    row = pl.BlockSpec((1, BLOCK_M), lambda i: (0, i))
+    return pl.pallas_call(
+        _kernel,
+        grid=(mp // BLOCK_M,),
+        in_specs=[pl.BlockSpec((k, s, BLOCK_M), lambda i: (0, 0, i))],
+        out_specs=[row, row],
+        out_shape=[jax.ShapeDtypeStruct((1, mp), draws.dtype)] * 2,
+        interpret=interpret,
+    )(draws)
+
+
+@jax.jit
+def residual_sample(u, xs):
     """u: (m, s, k) uniforms; xs: (n,) sorted trace.
     Returns (max_y: (m,), sum_y: (m,))."""
-    if interpret is None:
-        from repro.kernels import INTERPRET
-
-        interpret = INTERPRET
-    m, s, k = u.shape
+    m = u.shape[0]
     n = xs.shape[0]
-    pad_m = (-m) % block_m
-    if pad_m:
-        u = jnp.pad(u, ((0, pad_m), (0, 0), (0, 0)))
-    mp = u.shape[0]
-    grid = (mp // block_m,)
-    kernel = functools.partial(_kernel, n=n)
-    mx, sm = pl.pallas_call(
-        kernel,
-        grid=grid,
-        in_specs=[
-            pl.BlockSpec((block_m, s, k), lambda i: (i, 0, 0)),
-            pl.BlockSpec((n,), lambda i: (0,)),
-        ],
-        out_specs=[
-            pl.BlockSpec((block_m,), lambda i: (i,)),
-            pl.BlockSpec((block_m,), lambda i: (i,)),
-        ],
-        out_shape=[
-            jax.ShapeDtypeStruct((mp,), xs.dtype),
-            jax.ShapeDtypeStruct((mp,), xs.dtype),
-        ],
-        interpret=interpret,
-    )(u, xs)
-    return mx[:m], sm[:m]
+    # transpose before the gather: gathering into the (m, s, k) layout pads
+    # its k = r+1 minor axis to 128 lanes, ~40x the memory on a TPU
+    ut = jnp.pad(jnp.transpose(u, (2, 1, 0)), ((0, 0), (0, 0), (0, (-m) % BLOCK_M)))
+    draws = xs[jnp.clip(jnp.ceil(ut * n).astype(jnp.int32) - 1, 0, n - 1)]  # (k, s, m)
+    mx, sm = run_kernel(_call, draws)
+    return mx[0, :m], sm[0, :m]

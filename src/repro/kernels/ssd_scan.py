@@ -20,7 +20,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels import CompilerParams as _CompilerParams
+from repro.kernels import run_kernel
 
 
 def _kernel(x_ref, dt_ref, a_ref, b_ref, c_ref, d_ref, y_ref, hfin_ref, h_ref, *, chunk):
@@ -78,15 +78,11 @@ def _kernel(x_ref, dt_ref, a_ref, b_ref, c_ref, d_ref, y_ref, hfin_ref, h_ref, *
         hfin_ref[0, 0] = h_ref[...]
 
 
-@functools.partial(jax.jit, static_argnames=("chunk", "interpret"))
-def ssd_scan(x, dt, A, B, C, D, *, chunk: int = 128, interpret: bool | None = None):
+@functools.partial(jax.jit, static_argnames=("chunk",))
+def ssd_scan(x, dt, A, B, C, D, *, chunk: int = 128):
     """x: (Bt,S,H,P)  dt: (Bt,S,H)  A,D: (H,)  B,C: (Bt,S,G,N).
     Returns (y: (Bt,S,H,P), h_final: (Bt,H,P,N)).  Matches
     `repro.models.ssm.ssd_chunked` (zero initial state)."""
-    if interpret is None:
-        from repro.kernels import INTERPRET
-
-        interpret = INTERPRET
     Bt, S, H, P = x.shape
     G, N = B.shape[2], B.shape[3]
     S0 = S
@@ -110,7 +106,7 @@ def ssd_scan(x, dt, A, B, C, D, *, chunk: int = 128, interpret: bool | None = No
 
     grid = (Bt, H, nc)
     kernel = functools.partial(_kernel, chunk=chunk)
-    y, h_final = pl.pallas_call(
+    call = lambda *args, interpret: pl.pallas_call(  # noqa: E731
         kernel,
         grid=grid,
         in_specs=[
@@ -130,10 +126,11 @@ def ssd_scan(x, dt, A, B, C, D, *, chunk: int = 128, interpret: bool | None = No
             jax.ShapeDtypeStruct((Bt, H, P, N), jnp.float32),
         ],
         scratch_shapes=[pltpu.VMEM((P, N), jnp.float32)],
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary"),
         ),
         interpret=interpret,
-    )(xh, dth, Ah, Bh, Ch, Dh)
+    )(*args)
+    y, h_final = run_kernel(call, xh, dth, Ah, Bh, Ch, Dh)
     y = y.reshape(Bt, H, S, P).transpose(0, 2, 1, 3)[:, :S0]
     return y, h_final

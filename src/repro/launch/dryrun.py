@@ -1,6 +1,3 @@
-import os
-os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=512"
-
 """Multi-pod dry-run: lower + compile every (arch x shape x mesh) cell
 against ShapeDtypeStruct inputs, record memory/cost analysis + collective
 bytes parsed from the optimized HLO.
@@ -9,10 +6,12 @@ bytes parsed from the optimized HLO.
     PYTHONPATH=src python -m repro.launch.dryrun --arch qwen3-32b --shape train_4k --mesh multi
 
 Results are cached incrementally under benchmarks/results/dryrun/ so reruns
-skip completed cells (--force recomputes).
+skip completed cells (--force recomputes).  The command line forces 512
+host devices through XLA_FLAGS; importing this module sets nothing.
 """
 
 import argparse
+import os
 import json
 import re
 import time
@@ -26,6 +25,7 @@ from repro.launch import sharding as shd
 from repro.launch.mesh import make_production_mesh
 from repro.launch.shapes import SHAPES, applicability
 from repro.launch.steps import plan_decode, plan_prefill, plan_train
+from repro.obs.profile import shape_bytes
 
 RESULTS_DIR = Path(__file__).resolve().parents[3] / "benchmarks" / "results" / "dryrun"
 
@@ -33,23 +33,6 @@ _COLLECTIVE_RE = re.compile(
     r"\b(all-reduce|all-gather|reduce-scatter|all-to-all|collective-permute)\b"
 )
 _SHAPE_RE = re.compile(r"\b([a-z0-9]+)\[([0-9,]*)\]")
-
-_DTYPE_BYTES = {
-    "f64": 8, "f32": 4, "f16": 2, "bf16": 2, "f8e4m3fn": 1, "f8e5m2": 1,
-    "s64": 8, "u64": 8, "s32": 4, "u32": 4, "s16": 2, "u16": 2,
-    "s8": 1, "u8": 1, "pred": 1, "c64": 8, "c128": 16,
-}
-
-
-def _shape_bytes(dtype: str, dims: str) -> int:
-    nbytes = _DTYPE_BYTES.get(dtype)
-    if nbytes is None:
-        return 0
-    n = 1
-    if dims:
-        for d in dims.split(","):
-            n *= int(d)
-    return n * nbytes
 
 
 def collective_bytes(hlo_text: str) -> dict:
@@ -72,10 +55,10 @@ def collective_bytes(hlo_text: str) -> dict:
         call = line.split(op, 1)[1]
         shapes = _SHAPE_RE.findall(call)
         if shapes:
-            nbytes = sum(_shape_bytes(dt, dims) for dt, dims in shapes)
+            nbytes = sum(shape_bytes(dt, dims) for dt, dims in shapes)
         else:  # fall back to the result shape (before the '=')
             res = _SHAPE_RE.findall(line.split("=", 1)[1])
-            nbytes = _shape_bytes(*res[0]) if res else 0
+            nbytes = shape_bytes(*res[0]) if res else 0
         out[op] = out.get(op, 0) + nbytes
     return out
 
@@ -97,7 +80,7 @@ def bytes_by_op(hlo_text: str) -> dict:
         if not m:
             continue
         dtype, dims, op = m.groups()
-        agg[op] = agg.get(op, 0.0) + _shape_bytes(dtype, dims)
+        agg[op] = agg.get(op, 0.0) + shape_bytes(dtype, dims)
     return agg
 
 
@@ -218,6 +201,7 @@ def run_cell(arch: str, shape_name: str, mesh_kind: str, remat: str = "none",
     rec.update(
         status="OK",
         n_devices=mesh.devices.size,
+        device_kind=mesh.devices.flat[0].device_kind,
         lower_s=round(t_lower, 2),
         compile_s=round(t_compile + t_compile2, 2),
         memory=_memory_analysis_dict(compiled),
@@ -238,6 +222,8 @@ def _cell_path(arch, shape, mesh_kind, tag="") -> Path:
 
 
 def main():
+    # before the first device query: the backend reads XLA_FLAGS once
+    os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=512"
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", choices=list(ARCH_IDS), default=None)
     ap.add_argument("--shape", choices=list(SHAPES), default=None)

@@ -1,48 +1,25 @@
-import os
-os.environ.setdefault("XLA_FLAGS", "--xla_force_host_platform_device_count=512")
-
 """HLO byte/op profiler — the dry-run 'profiler' (no real hardware).
 
-Aggregates result-shape bytes by op kind over the optimized per-device HLO,
-splitting ops inside while loops (the layer scan — multiplied by trip
-count) from those outside.  This is what grounds the §Perf napkin math:
-'which op family moves the most HBM bytes?'.
+Aggregates result-shape bytes by op kind over the optimized per-device HLO
+(`repro.obs.profile.profile_hlo`), weighting ops inside while loops (the
+layer scan) by the trip count.  This is what grounds the §Perf napkin
+math: 'which op family moves the most HBM bytes?'.  The command line
+forces 512 host devices through XLA_FLAGS; importing this module sets
+nothing.
 
     PYTHONPATH=src python -m repro.launch.hlo_profile --arch deepseek-v2-236b \
         --shape train_4k --top 25
 """
 
 import argparse
-import re
-from collections import defaultdict
+import os
 
-_OP_RE = re.compile(r"=\s+([a-z0-9]+)\[([0-9,]*)\][^ ]*\s+([a-z0-9_-]+)")
-from repro.launch.dryrun import _shape_bytes
-
-
-def profile_hlo(hlo_text: str, scan_factor: float = 1.0) -> dict:
-    """bytes by op kind.  Ops inside `while` bodies get scan_factor weight
-    (= total scanned layers; cost analysis counts bodies once)."""
-    agg = defaultdict(float)
-    in_body = 0
-    for line in hlo_text.splitlines():
-        stripped = line.strip()
-        if re.match(r"%?[\w.-]*body[\w.-]*\s*\(", stripped) or "_body" in stripped.split("(")[0]:
-            if stripped.endswith("{"):
-                in_body = 1
-        if stripped == "}":
-            in_body = 0
-        m = _OP_RE.search(line)
-        if not m:
-            continue
-        dtype, dims, op = m.groups()
-        nbytes = _shape_bytes(dtype, dims)
-        weight = scan_factor if in_body else 1.0
-        agg[op] += nbytes * weight
-    return dict(agg)
+from repro.obs.profile import profile_hlo
 
 
 def main():
+    # before the first device query: the backend reads XLA_FLAGS once
+    os.environ.setdefault("XLA_FLAGS", "--xla_force_host_platform_device_count=512")
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", required=True)
     ap.add_argument("--shape", required=True)

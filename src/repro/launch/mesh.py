@@ -9,10 +9,23 @@ from __future__ import annotations
 
 import jax
 
-# TPU v5e hardware constants (roofline; see EXPERIMENTS.md §Roofline)
-PEAK_FLOPS_BF16 = 197e12  # FLOP/s per chip
-HBM_BW = 819e9  # B/s per chip
-ICI_BW = 50e9  # B/s per link
+#: published per-chip peaks, keyed by `device_kind` as JAX reports it
+#: (Google Cloud documentation, "TPU v5e"): bf16 FLOP/s, HBM bytes/s, and
+#: interconnect bytes/s per link (1,600 Gbit/s over four links)
+PEAKS = {
+    "TPU v5 lite": dict(flops_bf16=197e12, hbm_bw=819e9, ici_bw=50e9),
+}
+
+
+def peaks(device_kind: str) -> dict:
+    """The published peaks of one chip of `device_kind`; a kind with no
+    entry is an error, never a default."""
+    try:
+        return PEAKS[device_kind]
+    except KeyError:
+        raise ValueError(
+            f"no published peaks for device kind {device_kind!r}; known: {sorted(PEAKS)}"
+        ) from None
 
 
 def make_production_mesh(*, multi_pod: bool = False):

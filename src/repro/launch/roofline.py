@@ -2,9 +2,12 @@
 
 Per (arch x shape x mesh) cell, from the compiled per-device HLO:
 
-  compute term    = HLO_FLOPs_global / (chips x 197e12 FLOP/s)
-  memory term     = HLO_bytes_global / (chips x 819e9 B/s)
-  collective term = collective_bytes_per_device / 50e9 B/s per link
+  compute term    = HLO_FLOPs_global / (chips x peak FLOP/s)
+  memory term     = HLO_bytes_global / (chips x peak HBM B/s)
+  collective term = collective_bytes_per_device / peak B/s per link
+
+with the peaks of the device kind the cell was compiled for
+(`repro.launch.mesh.peaks`; a kind without published peaks is an error).
 
 cost_analysis() on the partitioned module reports PER-DEVICE numbers, so
 globals are per-device x chips; the collective term uses per-device bytes
@@ -21,7 +24,7 @@ import json
 from pathlib import Path
 
 from repro.configs import get_config
-from repro.launch.mesh import HBM_BW, ICI_BW, PEAK_FLOPS_BF16
+from repro.launch.mesh import peaks
 from repro.launch.shapes import SHAPES
 
 RESULTS_DIR = Path(__file__).resolve().parents[3] / "benchmarks" / "results" / "dryrun"
@@ -59,10 +62,11 @@ def analyze_cell(rec: dict) -> dict:
     # (see dryrun._ALIAS_OPS); fall back to raw cost-analysis bytes
     bytes_dev = rec.get("bytes_adjusted", bytes_raw)
     coll_dev = sum(rec.get("collectives", {}).values())
+    peak = peaks(rec["device_kind"])
 
-    t_compute = flops_dev * chips / (chips * PEAK_FLOPS_BF16)  # = flops_dev / peak
-    t_memory = bytes_dev * chips / (chips * HBM_BW)
-    t_collective = coll_dev / ICI_BW
+    t_compute = flops_dev * chips / (chips * peak["flops_bf16"])  # = flops_dev / peak
+    t_memory = bytes_dev * chips / (chips * peak["hbm_bw"])
+    t_collective = coll_dev / peak["ici_bw"]
 
     terms = {"compute": t_compute, "memory": t_memory, "collective": t_collective}
     dominant = max(terms, key=terms.get)
@@ -70,11 +74,12 @@ def analyze_cell(rec: dict) -> dict:
     hlo_global = flops_dev * chips
     bound = max(terms.values())
     # roofline fraction: useful-FLOPs time at peak vs the dominant term
-    t_useful = mf / (chips * PEAK_FLOPS_BF16)
+    t_useful = mf / (chips * peak["flops_bf16"])
     return {
         "arch": arch,
         "shape": shape_name,
         "mesh": rec["mesh"],
+        "device_kind": rec["device_kind"],
         "tag": rec.get("tag", ""),
         "chips": chips,
         "hlo_flops_per_dev": flops_dev,

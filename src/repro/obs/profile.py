@@ -2,11 +2,10 @@
 
 `kernel_profile` is the obs-side wrapper for the fused engines and the
 Pallas `kw_queue` kernel: lower + compile once (timed), pull bytes-by-op
-from the optimized HLO via `repro.launch.hlo_profile.profile_hlo`, ask
-the compiled executable for its memory footprint (`memory_analysis()` —
-temp/argument/output bytes; this is the VMEM/scratch figure on real
-accelerators, guarded because some backends do not implement it), then
-time steady-state execution with `block_until_ready` over a few repeats.
+from the optimized HLO (`profile_hlo`), ask the compiled executable for
+its memory footprint (`memory_analysis()` — temp/argument/output bytes),
+then time steady-state execution with `block_until_ready` over a few
+repeats.
 
 Results land in three places at once: returned as a plain dict, recorded
 as spans/counters on a trace recorder (profiler pid), and gauged into a
@@ -16,7 +15,9 @@ metrics view all see the same numbers.
 
 from __future__ import annotations
 
+import re
 import time
+from collections import defaultdict
 from typing import Optional
 
 import jax
@@ -24,7 +25,49 @@ import jax
 from .registry import MetricsRegistry
 from .trace import PID_PROFILER, NULL_RECORDER, Recorder, NullRecorder
 
-__all__ = ["kernel_profile", "jit_cache_size", "RetraceWatch"]
+__all__ = ["kernel_profile", "jit_cache_size", "profile_hlo", "shape_bytes", "RetraceWatch"]
+
+_DTYPE_BYTES = {
+    "f64": 8, "f32": 4, "f16": 2, "bf16": 2, "f8e4m3fn": 1, "f8e5m2": 1,
+    "s64": 8, "u64": 8, "s32": 4, "u32": 4, "s16": 2, "u16": 2,
+    "s8": 1, "u8": 1, "pred": 1, "c64": 8, "c128": 16,
+}
+
+_OP_RE = re.compile(r"=\s+([a-z0-9]+)\[([0-9,]*)\][^ ]*\s+([a-z0-9_-]+)")
+
+
+def shape_bytes(dtype: str, dims: str) -> int:
+    """Bytes of one HLO array shape (`f32`, `"16,1024"`); 0 for a dtype
+    outside the table."""
+    nbytes = _DTYPE_BYTES.get(dtype)
+    if nbytes is None:
+        return 0
+    n = 1
+    if dims:
+        for d in dims.split(","):
+            n *= int(d)
+    return n * nbytes
+
+
+def profile_hlo(hlo_text: str, scan_factor: float = 1.0) -> dict:
+    """bytes by op kind.  Ops inside `while` bodies get scan_factor weight
+    (= total scanned layers; cost analysis counts bodies once)."""
+    agg = defaultdict(float)
+    in_body = 0
+    for line in hlo_text.splitlines():
+        stripped = line.strip()
+        if re.match(r"%?[\w.-]*body[\w.-]*\s*\(", stripped) or "_body" in stripped.split("(")[0]:
+            if stripped.endswith("{"):
+                in_body = 1
+        if stripped == "}":
+            in_body = 0
+        m = _OP_RE.search(line)
+        if not m:
+            continue
+        dtype, dims, op = m.groups()
+        weight = scan_factor if in_body else 1.0
+        agg[op] += shape_bytes(dtype, dims) * weight
+    return dict(agg)
 
 
 def jit_cache_size(fn) -> Optional[int]:
@@ -72,19 +115,14 @@ class RetraceWatch:
 
 
 def _memory_analysis(compiled) -> dict:
-    """Executable memory footprint, empty if the backend lacks the API."""
-    try:
-        ma = compiled.memory_analysis()
-        return {
-            "temp_bytes": int(getattr(ma, "temp_size_in_bytes", 0)),
-            "argument_bytes": int(getattr(ma, "argument_size_in_bytes", 0)),
-            "output_bytes": int(getattr(ma, "output_size_in_bytes", 0)),
-            "generated_code_bytes": int(
-                getattr(ma, "generated_code_size_in_bytes", 0)
-            ),
-        }
-    except Exception:
-        return {}
+    """Executable memory footprint (`memory_analysis()`, in bytes)."""
+    ma = compiled.memory_analysis()
+    return {
+        "temp_bytes": int(ma.temp_size_in_bytes),
+        "argument_bytes": int(ma.argument_size_in_bytes),
+        "output_bytes": int(ma.output_size_in_bytes),
+        "generated_code_bytes": int(ma.generated_code_size_in_bytes),
+    }
 
 
 def kernel_profile(
@@ -101,11 +139,6 @@ def kernel_profile(
     """Compile-and-time `fn(*args, **kwargs)`; returns a profile dict with
     compile_s, best/mean wall_s, bytes-by-op (top HLO movers), and the
     executable's memory footprint."""
-    # deferred: importing repro.launch.hlo_profile sets XLA_FLAGS for the
-    # 512-device dry-run, which must not happen from a plain `import
-    # repro.obs` before jax picks its backend
-    from repro.launch.hlo_profile import profile_hlo
-
     jitted = jax.jit(fn, static_argnames=static_argnames)
 
     t0 = time.perf_counter()
@@ -148,8 +181,5 @@ def kernel_profile(
     if registry is not None:
         registry.gauge("kernel_wall_s", {"kernel": name}).set(prof["wall_s"])
         registry.gauge("kernel_compile_s", {"kernel": name}).set(compile_s)
-        if "temp_bytes" in mem:
-            registry.gauge("kernel_temp_bytes", {"kernel": name}).set(
-                mem["temp_bytes"]
-            )
+        registry.gauge("kernel_temp_bytes", {"kernel": name}).set(mem["temp_bytes"])
     return prof

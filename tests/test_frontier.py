@@ -113,6 +113,37 @@ def test_frontier_kernel_switch_is_exact():
         assert a["p99"] == pytest.approx(b["p99"], rel=1e-5)
 
 
+def _backend_compiles(call):
+    """Backend compilations `call()` triggers (JAX's own compile events)."""
+    seen = []
+
+    def listener(name, duration, **kwargs):
+        if name == "/jax/core/compile/backend_compile_duration":
+            seen.append(duration)
+
+    jax.monitoring.register_event_duration_secs_listener(listener)
+    try:
+        call()
+    finally:
+        jax.monitoring.unregister_event_duration_listener(listener)
+    return len(seen)
+
+
+@pytest.mark.parametrize("kernel", [False, True])
+def test_lower_frontier_is_the_program_frontier_runs(kernel):
+    """lower_frontier hands out the exact program frontier dispatches: once
+    it is compiled, frontier and a same-shaped policy_search compile
+    nothing more."""
+    kw = dict(m_trials=5, c=2, kernel=kernel, r_cap=3)
+    pols, lams = POLICIES[:2], (0.2, 0.3)
+    compiled = vector.lower_frontier(DIST, pols, lams, N, 77, **kw).compile()
+    assert compiled.memory_analysis().temp_size_in_bytes > 0
+    assert _backend_compiles(lambda: vector.frontier(DIST, pols, lams, N, 77, **kw)) == 0
+    # policy_search at one λ is the same engine: one more program, no more
+    vector.lower_frontier(DIST, pols, (0.2,), N, 77, **kw).compile()
+    assert _backend_compiles(lambda: vector.policy_search(DIST, pols, 0.25, N, 77, **kw)) == 0
+
+
 def test_sweep_is_a_frontier_wrapper():
     key = jax.random.PRNGKey(7)
     s = vector.sweep(DIST, POLICIES, LAMS, N, 100, m_trials=8, key=key)
