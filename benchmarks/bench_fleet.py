@@ -8,7 +8,7 @@ Measurements:
   * the cross-family frontier lane: one grid mixing every policy-algebra
     family (classic single fork, delayed relaunch, (n, d) group selection,
     multi-fork schedules) — gated on (a) the whole mixed grid evaluating
-    as ONE device dispatch (the engine's own `frontier_dispatch` span is
+    as ONE device dispatch (the engine's own `grid.dispatch` span is
     the witness) and (b) algebra-lowered single-fork cells matching the
     pre-refactor fused frontier numbers exactly, float for float;
   * the adaptive controller's re-plan latency: the padded fused search
@@ -459,7 +459,7 @@ def run():
             f"fused frontier ({algebra_mismatch} field mismatches)"
         )
     # gate 2: a grid MIXING every family is still one fused device dispatch
-    # (witnessed by the engine's own frontier_dispatch span)
+    # (witnessed by the engine's own grid.dispatch span)
     cross_key = jax.random.PRNGKey(23)
     vector.frontier(
         DIST, CROSS_POLICIES, CROSS_LAMS, N_TASKS, N_JOBS, m_trials=M_TRIALS,
@@ -475,7 +475,7 @@ def run():
         cross_s = time.perf_counter() - t0
     finally:
         obs_trace.disable()
-    dispatches = cross_rec.spans_named("frontier_dispatch")
+    dispatches = cross_rec.spans_named("grid.dispatch")
     n_cross_cells = len(CROSS_POLICIES) * len(CROSS_LAMS)
     one_dispatch = (
         len(dispatches) == 1 and dispatches[0].args["cells"] == n_cross_cells

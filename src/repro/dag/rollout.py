@@ -55,6 +55,8 @@ from repro.core.policy import SingleForkPolicy, lower_policies, max_replicas
 from repro.core.simulate import lowered_policy_eval, policy_draws
 from repro.fleet.vector import (
     _fault_qs,
+    _fetch_grid,
+    _grid_tails,
     as_quantile_source,
     batched_queue,
     cell_bucket,
@@ -64,6 +66,7 @@ from repro.fleet.vector import (
     retry_draws,
     retry_transform,
 )
+from repro.obs.trace import host_span
 
 from .graph import JobDAG
 
@@ -460,58 +463,43 @@ def _eval_dag_cells(
     `cell_qs` (one per cell, static draw width `attempts`) runs every stage
     under the geometric-retry transform; None keeps the historical
     bit-identical programs."""
-    cell_vectors = [dag.validate_policy_vector(v) for v in cell_vectors]
-    args, kwargs, hist = _dag_cells_call(
-        dag, cell_vectors, cell_lams, n_jobs, m_trials, key, kernel, r_caps,
-        pad_cells, tail, cell_qs, attempts,
-    )
-    n_cells = len(cell_vectors)
-    from repro.obs.device import sketch_from_device
-
-    stats, payload = _dag_stats_jit(*args, **kwargs)
-    stats = np.asarray(stats)[:n_cells]
-    if hist is None:
-        soj = np.asarray(payload)[:n_cells]
-        pcts = np.percentile(soj, (50.0, 99.0, 99.9), axis=1)
-        cost_pcts = None
-    else:
-        from repro.obs.evtail import evt_keys
-
-        s_counts, s_agg, c_counts, c_agg = (np.asarray(p)[:n_cells] for p in payload)
-        pcts = np.empty((3, n_cells))
-        cost_pcts = np.empty((3, n_cells))
-        # hist rows also carry the EVT tail extension (same contract as
-        # the fleet frontier): GPD on the end-to-end sojourn sketch
-        cell_evt = []
-        for i in range(n_cells):
-            sk = sketch_from_device(s_counts[i], *s_agg[i], spec=hist)
-            pcts[:, i] = sk.quantiles((0.5, 0.99, 0.999))
-            cell_evt.append(evt_keys(sk))
-            ck = sketch_from_device(c_counts[i], *c_agg[i], spec=hist)
-            cost_pcts[:, i] = ck.quantiles((0.5, 0.99, 0.999))
-    rows = []
-    nk = len(_DAG_JIT_KEYS)
-    nsk = len(_DAG_STAGE_KEYS)
-    for i, (vec, lam) in enumerate(zip(cell_vectors, cell_lams)):
-        row = dict(
-            lam=float(lam),
-            policies=tuple(vec),
-            label=vector_label(vec, dag),
-            **dict(zip(_DAG_JIT_KEYS, map(float, stats[i, :nk]))),
+    with host_span("grid.lower"):
+        cell_vectors = [dag.validate_policy_vector(v) for v in cell_vectors]
+        args, kwargs, hist = _dag_cells_call(
+            dag, cell_vectors, cell_lams, n_jobs, m_trials, key, kernel, r_caps,
+            pad_cells, tail, cell_qs, attempts,
         )
-        if cell_qs is not None:
-            row["q"] = float(cell_qs[i])
-        row["p50"], row["p99"], row["p999"] = (float(pcts[j, i]) for j in range(3))
-        if cost_pcts is not None:
-            row["cost_p50"], row["cost_p99"], row["cost_p999"] = (
-                float(cost_pcts[j, i]) for j in range(3)
+    n_cells = len(cell_vectors)
+    with host_span("grid.dispatch", cells=n_cells,
+                   padded=cell_bucket(n_cells) if pad_cells else n_cells):
+        stats, payload = _dag_stats_jit(*args, **kwargs)
+    with host_span("grid.fetch"):
+        stats, payload = _fetch_grid(stats, payload, hist, n_cells)
+    with host_span("grid.tail"):
+        pcts, cost_pcts, cell_evt = _grid_tails(payload, hist, n_cells)
+        rows = []
+        nk = len(_DAG_JIT_KEYS)
+        nsk = len(_DAG_STAGE_KEYS)
+        for i, (vec, lam) in enumerate(zip(cell_vectors, cell_lams)):
+            row = dict(
+                lam=float(lam),
+                policies=tuple(vec),
+                label=vector_label(vec, dag),
+                **dict(zip(_DAG_JIT_KEYS, map(float, stats[i, :nk]))),
             )
-            row.update(cell_evt[i])
-        for s, spec in enumerate(dag.stages):
-            off = nk + s * nsk
-            for j, k in enumerate(_DAG_STAGE_KEYS):
-                row[f"{spec.name}/{k}"] = float(stats[i, off + j])
-        rows.append(row)
+            if cell_qs is not None:
+                row["q"] = float(cell_qs[i])
+            row["p50"], row["p99"], row["p999"] = (float(pcts[j, i]) for j in range(3))
+            if cost_pcts is not None:
+                row["cost_p50"], row["cost_p99"], row["cost_p999"] = (
+                    float(cost_pcts[j, i]) for j in range(3)
+                )
+                row.update(cell_evt[i])
+            for s, spec in enumerate(dag.stages):
+                off = nk + s * nsk
+                for j, k in enumerate(_DAG_STAGE_KEYS):
+                    row[f"{spec.name}/{k}"] = float(stats[i, off + j])
+            rows.append(row)
     return rows
 
 

@@ -71,6 +71,8 @@ import numpy as np
 from repro.core.distributions import Distribution, Empirical, quantile_draws
 from repro.core.policy import SingleForkPolicy, lower_policies, num_stragglers
 from repro.core.simulate import lowered_policy_eval, policy_draws, single_fork_batch
+from repro.obs.profile import jit_cache_size
+from repro.obs.trace import get_recorder, host_span
 
 from .workload import MachineClass
 
@@ -954,83 +956,85 @@ def _eval_cells(
     γ-bucket bincounts — p50/p99/p999 then carry the sketch's relative-
     accuracy guarantee, the off-device transfer is fixed-size per cell,
     and rows additionally get cost_p50/cost_p99/cost_p999."""
-    fn, args, hist, names = _cells_call(
-        dist_or_samples, cell_policies, cell_lams, n, n_jobs, m_trials, key, c,
-        classes, kernel, r_cap, pad_cells, tail, cell_qs, attempts,
-    )
-    n_cells = len(cell_policies)
-    from repro.obs.device import sketch_from_device
-    from repro.obs.profile import jit_cache_size
-    from repro.obs.trace import PID_PROFILER, get_recorder
-
-    rec = get_recorder()
-    # re-trace detection (obs.retrace): the padded-grid contract promises
-    # that re-plans inside one geometry never recompile — observe it by
-    # watching the jit cache across the dispatch
-    _cache_before = jit_cache_size(fn)
-    if rec.enabled:
-        import time as _time
-
-        t0 = _time.perf_counter()
-    stats, payload = fn(*args, hist=hist)
-    if rec.enabled:
-        jax.block_until_ready((stats, payload))
-        rec.span(
-            "frontier_dispatch", "engine", t0, _time.perf_counter() - t0,
-            pid=PID_PROFILER,
-            args=dict(cells=n_cells,
-                      padded=cell_bucket(n_cells) if pad_cells else n_cells,
-                      m_trials=m_trials,
-                      n_jobs=n_jobs, tail="exact" if hist is None else "hist"),
+    with host_span("grid.lower"):
+        fn, args, hist, names = _cells_call(
+            dist_or_samples, cell_policies, cell_lams, n, n_jobs, m_trials, key, c,
+            classes, kernel, r_cap, pad_cells, tail, cell_qs, attempts,
         )
-        rec.count("frontier.cells", n_cells)
-        _cache_after = jit_cache_size(fn)
-        if _cache_before is not None and _cache_after is not None:
-            delta = _cache_after - _cache_before
-            if delta > 0:
-                rec.count("obs.retrace", delta)
+    n_cells = len(cell_policies)
+    rec = get_recorder()
+    if rec.enabled:
+        # re-trace detection (obs.retrace): the padded-grid contract promises
+        # that re-plans inside one geometry never recompile — observe it by
+        # watching the jit cache across the dispatch
+        cache_before = jit_cache_size(fn)
+    with host_span("grid.dispatch", cells=n_cells,
+                   padded=cell_bucket(n_cells) if pad_cells else n_cells):
+        stats, payload = fn(*args, hist=hist)
+    if rec.enabled:
+        cache_after = jit_cache_size(fn)
+        if cache_before is not None and cache_after is not None and cache_after > cache_before:
+            rec.count("obs.retrace", cache_after - cache_before)
+    with host_span("grid.fetch"):
+        stats, payload = _fetch_grid(stats, payload, hist, n_cells)
+    with host_span("grid.tail"):
+        pcts, cost_pcts, cell_evt = _grid_tails(payload, hist, n_cells)
+        rows = []
+        nk = len(_FRONTIER_JIT_KEYS)
+        for i, (pol, lam) in enumerate(zip(cell_policies, cell_lams)):
+            row = stats[i]
+            d = dict(lam=float(lam), policy=pol.label(),
+                     **dict(zip(_FRONTIER_JIT_KEYS, map(float, row[:nk]))))
+            if cell_qs is not None:
+                d["q"] = float(cell_qs[i])
+            d["p50"], d["p99"], d["p999"] = (float(pcts[j, i]) for j in range(3))
+            if cost_pcts is not None:
+                d["cost_p50"], d["cost_p99"], d["cost_p999"] = (
+                    float(cost_pcts[j, i]) for j in range(3)
+                )
+                d.update(cell_evt[i])
+            if names is not None:  # mirror VectorFleetResult.summary(): per-class util
+                for name, u in zip(names, row[nk:]):
+                    d[f"util_{name}"] = float(u)
+            rows.append(d)
+    return rows
+
+
+def _fetch_grid(stats, payload, hist, n_cells: int):
+    """One grid's stats rows and tail payload on the host, cut to its real
+    cells: the wait for the program and the device-to-host copy.  The
+    payload is the sojourn matrix for the exact tail, else the tuple of
+    sojourn and cost bincounts with their (min, max, sum) aggregates."""
     stats = np.asarray(stats)[:n_cells]
     if hist is None:
-        soj = np.asarray(payload)[:n_cells].reshape(n_cells, -1)
-        pcts = np.percentile(soj, (50.0, 99.0, 99.9), axis=1)
-        cost_pcts = None
-    else:
-        from repro.obs.evtail import evt_keys
+        return stats, np.asarray(payload)[:n_cells]
+    return stats, tuple(np.asarray(p)[:n_cells] for p in payload)
 
-        s_counts, s_agg, c_counts, c_agg = (np.asarray(p)[:n_cells] for p in payload)
-        pcts = np.empty((3, n_cells))
-        cost_pcts = np.empty((3, n_cells))
-        # hist cells carry the whole tail shape, so each row additionally
-        # gets the EVT extension (evt_xi / evt_p999 / evt_p9999): a GPD
-        # fitted on the reconstructed sketch's exceedance buckets
-        # extrapolates past the (n_jobs × m_trials) sample's resolution —
-        # the ROADMAP's "p999/p9999 from EVT rather than raw MC"
-        cell_evt = []
-        for i in range(n_cells):
-            sk = sketch_from_device(s_counts[i], *s_agg[i], spec=hist)
-            pcts[:, i] = sk.quantiles((0.5, 0.99, 0.999))
-            cell_evt.append(evt_keys(sk))
-            ck = sketch_from_device(c_counts[i], *c_agg[i], spec=hist)
-            cost_pcts[:, i] = ck.quantiles((0.5, 0.99, 0.999))
-    rows = []
-    nk = len(_FRONTIER_JIT_KEYS)
-    for i, (pol, lam) in enumerate(zip(cell_policies, cell_lams)):
-        row = stats[i]
-        d = dict(lam=float(lam), policy=pol.label(),
-                 **dict(zip(_FRONTIER_JIT_KEYS, map(float, row[:nk]))))
-        if cell_qs is not None:
-            d["q"] = float(cell_qs[i])
-        d["p50"], d["p99"], d["p999"] = (float(pcts[j, i]) for j in range(3))
-        if cost_pcts is not None:
-            d["cost_p50"], d["cost_p99"], d["cost_p999"] = (
-                float(cost_pcts[j, i]) for j in range(3)
-            )
-            d.update(cell_evt[i])
-        if names is not None:  # mirror VectorFleetResult.summary(): per-class util
-            for name, u in zip(names, row[nk:]):
-                d[f"util_{name}"] = float(u)
-        rows.append(d)
-    return rows
+
+def _grid_tails(payload, hist, n_cells: int):
+    """p50/p99/p999 of each cell's sojourns, a (3, n_cells) array, from
+    `_fetch_grid`'s payload.  The exact tail gives (pcts, None, None).  Hist
+    cells carry the whole tail shape, so they also give the cost
+    percentiles and each cell's EVT extension (evt_xi / evt_p999 /
+    evt_p9999): a GPD fitted on the reconstructed sketch's exceedance
+    buckets extrapolates past the (n_jobs × m_trials) sample's resolution."""
+    if hist is None:
+        soj = payload.reshape(n_cells, -1)
+        return np.percentile(soj, (50.0, 99.0, 99.9), axis=1), None, None
+    from repro.obs.device import sketch_from_device
+    from repro.obs.evtail import evt_keys
+
+    s_counts, s_agg, c_counts, c_agg = payload
+    pcts = np.empty((3, n_cells))
+    cost_pcts = np.empty((3, n_cells))
+    cell_evt = []
+    for i in range(n_cells):
+        sk = sketch_from_device(s_counts[i], *s_agg[i], spec=hist)
+        pcts[:, i] = sk.quantiles((0.5, 0.99, 0.999))
+        cell_evt.append(evt_keys(sk))
+        ck = sketch_from_device(c_counts[i], *c_agg[i], spec=hist)
+        cost_pcts[:, i] = ck.quantiles((0.5, 0.99, 0.999))
+    return pcts, cost_pcts, cell_evt
 
 
 def _fault_qs(fault):

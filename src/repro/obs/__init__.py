@@ -5,7 +5,8 @@ One package, four capabilities (DESIGN.md §13):
   * `sketch`    — mergeable streaming quantile sketch (DDSketch-style);
   * `registry`  — counters / gauges / sketch-backed histograms with labels;
   * `trace`     — span recorder + NullRecorder zero-cost-when-disabled
-    protocol; `export` renders Chrome trace-event JSON for Perfetto;
+    protocol, and `host_span` on the JAX profiler's clock; `export`
+    renders Chrome trace-event JSON for Perfetto;
   * `decisions` — structured decision log for the adaptive controller;
   * `device`    — in-program γ-bucket histograms for the fused engines;
   * `profile`   — wall-time / HLO-byte / memory profiling of jitted fns,
@@ -74,13 +75,14 @@ from .trace import (  # noqa: F401
     disable,
     enable,
     get_recorder,
+    host_span,
 )
 
 __all__ = [
     "QuantileSketch", "merge_all",
     "Counter", "Gauge", "Histogram", "MetricsRegistry",
     "Recorder", "NullRecorder", "NULL_RECORDER",
-    "enable", "disable", "get_recorder",
+    "enable", "disable", "get_recorder", "host_span",
     "PID_FLEET", "PID_CONTROLLER", "PID_SERVING", "PID_PROFILER",
     "PID_DAG_BASE",
     "DecisionEvent", "DecisionLog",

@@ -24,16 +24,23 @@ Span/instant pids partition the trace into Perfetto "processes":
 scheduler lifecycle rows, controller decisions, serving, kernel
 profiling, and one row per DAG stage.  `repro.obs.export` turns a
 Recorder into Chrome trace-event JSON.
+
+`host_span` marks a stretch of host code on the JAX profiler's own clock,
+the one its device planes are aligned to, and mirrors it into the
+process-wide recorder when that is enabled.
 """
 
 from __future__ import annotations
 
 import dataclasses
+import time
 from typing import Mapping, Optional
+
+from jax.profiler import TraceAnnotation
 
 __all__ = [
     "Span", "Instant", "CounterSample", "Recorder", "NullRecorder",
-    "NULL_RECORDER", "enable", "disable", "get_recorder",
+    "NULL_RECORDER", "enable", "disable", "get_recorder", "host_span",
     "PID_FLEET", "PID_CONTROLLER", "PID_SERVING", "PID_PROFILER",
     "PID_DAG_BASE",
 ]
@@ -207,6 +214,37 @@ def disable() -> None:
 def get_recorder() -> Recorder | NullRecorder:
     """The process-wide recorder (NullRecorder unless `enable()` was called)."""
     return _current
+
+
+class _RecordedSpan:
+    """A `TraceAnnotation` that also appends its span to a live recorder."""
+
+    __slots__ = ("name", "args", "rec", "_ann", "_t0")
+
+    def __init__(self, name: str, args: dict, rec: Recorder):
+        self.name, self.args, self.rec = name, args, rec
+        self._ann = TraceAnnotation(name, **args)
+
+    def __enter__(self):
+        self._t0 = time.perf_counter()
+        self._ann.__enter__()
+        return self
+
+    def __exit__(self, *exc):
+        self._ann.__exit__(*exc)
+        self.rec.span(self.name, "host", self._t0, time.perf_counter() - self._t0,
+                      pid=PID_PROFILER, args=self.args)
+
+
+def host_span(name: str, **args):
+    """Context manager for one stretch of host code: a
+    `jax.profiler.TraceAnnotation`, on the profiler's clock (about 1 µs
+    without the profiler), and, while the process-wide recorder is enabled,
+    also a `Span` on the profiler pid in wall seconds.  It never waits on a
+    device value."""
+    if not _current.enabled:
+        return TraceAnnotation(name, **args)
+    return _RecordedSpan(name, args, _current)
 
 
 def resolve_recorder(obs) -> Optional[Recorder]:

@@ -405,9 +405,105 @@ def test_frontier_emits_dispatch_span_when_enabled():
         vector.frontier(ShiftedExp(1.0, 1.0), pols, (0.1,), 8, 64, m_trials=4)
     finally:
         obs_trace.disable()
-    spans = rec.spans_named("frontier_dispatch")
+    spans = rec.spans_named("grid.dispatch")
     assert len(spans) == 1 and spans[0].pid == obs_trace.PID_PROFILER
-    assert rec.counters["frontier.cells"] == 1
+    assert spans[0].args == {"cells": 1, "padded": 8}
+
+
+GRID_SPANS = ("grid.lower", "grid.dispatch", "grid.fetch", "grid.tail")
+
+
+def _grid_call(engine, tail="exact"):
+    """One small grid evaluation of `engine` ("fleet" or "dag")."""
+    import jax
+
+    from repro.core import ShiftedExp, SingleForkPolicy
+
+    base, kill = SingleForkPolicy(0.0, 0, True), SingleForkPolicy(0.2, 1, False)
+    kw = dict(n_jobs=64, m_trials=4, key=jax.random.PRNGKey(5), tail=tail)
+    if engine == "fleet":
+        from repro.fleet import frontier
+
+        return frontier(ShiftedExp(1.0, 1.0), (base, kill), (0.1, 0.2), 8, **kw)
+    from repro.dag import JobDAG, dag_frontier
+
+    dag = JobDAG.map_reduce(4, 2, ShiftedExp(1.0, 1.0), ShiftedExp(1.0, 0.5))
+    return dag_frontier(dag, [(base, base), (kill, base)], (0.1, 0.2), **kw)
+
+
+def _in_order_without_overlap(spans):
+    """(name, start, end) triples: the four grid spans once each, in order,
+    each ending before the next starts."""
+    assert [name for name, _, _ in spans] == list(GRID_SPANS)
+    for (_, s, e), (_, s_next, _) in zip(spans, spans[1:]):
+        assert s <= e <= s_next
+
+
+@pytest.mark.parametrize("engine", ["fleet", "dag"])
+def test_grid_spans_on_the_profiler_clock(engine, tmp_path):
+    import jax
+
+    _grid_call(engine)  # compile outside the trace
+    with jax.profiler.trace(str(tmp_path)):
+        _grid_call(engine)
+    (path,) = tmp_path.rglob("*.xplane.pb")
+    data = jax.profiler.ProfileData.from_file(str(path))
+    spans = sorted(
+        ((ev.name, ev.start_ns, ev.start_ns + ev.duration_ns)
+         for plane in data.planes for line in plane.lines for ev in line.events
+         if ev.name in GRID_SPANS),
+        key=lambda sp: sp[1],
+    )
+    _in_order_without_overlap(spans)
+
+
+@pytest.mark.parametrize("engine", ["fleet", "dag"])
+def test_grid_spans_reach_the_recorder(engine):
+    rec = obs_trace.enable()
+    try:
+        _grid_call(engine)
+    finally:
+        obs_trace.disable()
+    spans = sorted(((s.name, s.ts, s.ts + s.dur) for s in rec.spans
+                    if s.name in GRID_SPANS), key=lambda sp: sp[1])
+    _in_order_without_overlap(spans)
+    assert all(s.pid == obs_trace.PID_PROFILER for s in rec.spans)
+    assert rec.spans_named("grid.dispatch")[0].args == {"cells": 4, "padded": 8}
+
+
+@pytest.mark.parametrize("tail", ["exact", "hist"])
+@pytest.mark.parametrize("engine", ["fleet", "dag"])
+def test_rows_bitwise_equal_with_recorder_on_and_off(engine, tail):
+    off = _grid_call(engine, tail)
+    obs_trace.enable()
+    try:
+        on = _grid_call(engine, tail)
+    finally:
+        obs_trace.disable()
+    assert [list(r) for r in on] == [list(r) for r in off]
+    for r_on, r_off in zip(on, off):
+        for k, v in r_off.items():
+            same_nan = isinstance(v, float) and v != v and r_on[k] != r_on[k]
+            assert r_on[k] == v or same_nan, k
+
+
+def test_grid_path_waits_on_nothing_while_disabled(monkeypatch):
+    """With the recorder off the grid path is its statements plus bare
+    profiler annotations: no jit-cache reads, no forced device waits."""
+    import jax
+    from jax.profiler import TraceAnnotation
+
+    from repro.fleet import vector
+
+    def refuse(*a, **k):
+        raise AssertionError("called with the recorder disabled")
+
+    assert not obs_trace.get_recorder().enabled
+    assert type(obs_trace.host_span("grid.tail", cells=3)) is TraceAnnotation
+    monkeypatch.setattr(vector, "jit_cache_size", refuse)
+    monkeypatch.setattr(jax, "block_until_ready", refuse)
+    for engine in ("fleet", "dag"):
+        assert _grid_call(engine)
 
 
 def test_histspec_alignment():
