@@ -426,10 +426,20 @@ def fleet_rollout(
 
 def emp_quantile(xs, u):
     """Inverse-transform gather through the sorted empirical sample
-    (type-1 inverse, identical to `core.distributions.Empirical.quantile`)."""
+    (type-1 inverse, identical to `core.distributions.Empirical.quantile`).
+
+    In a program lowered for a TPU, a float32 table of at most
+    `Empirical.LANE_GATHER_MAX` entries is looked up in VMEM by
+    `Empirical.lane_gather`, with the same values; every other platform,
+    and a larger table, keeps XLA's gather."""
     m = xs.shape[0]
-    idx = jnp.clip(jnp.ceil(u * m).astype(jnp.int32) - 1, 0, m - 1)
-    return xs[idx]
+
+    def gather(xs, u):
+        return xs[Empirical.type1_index(u, m)]
+
+    if m > Empirical.LANE_GATHER_MAX or not xs.dtype == u.dtype == jnp.float32:
+        return gather(xs, u)
+    return jax.lax.platform_dependent(xs, u, tpu=Empirical.lane_gather, default=gather)
 
 
 def batched_queue(arrivals, services, speeds, kernel: bool = False):

@@ -1,10 +1,14 @@
 """Pallas kernel sweeps vs pure-jnp oracles (interpret mode on CPU)."""
 
+import re
+
 import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
 
+from repro.core.distributions import Empirical
+from repro.fleet.vector import emp_quantile
 from repro.kernels import ops, ref
 from repro.models.ssm import ssd_chunked
 
@@ -176,3 +180,66 @@ def test_residual_sampler_is_min_of_replicas_distribution():
     mean_y = float(jnp.mean(sm)) / s
     # min of r+1 Exp(1) ~ Exp(r+1): mean 1/3
     assert mean_y == pytest.approx(1 / 3, rel=0.05)
+
+
+# ------------------------------------------- empirical inverse in VMEM
+def _emp_gather(xs, u):
+    m = xs.shape[0]
+    return xs[jnp.clip(jnp.ceil(u * m).astype(jnp.int32) - 1, 0, m - 1)]
+
+
+def _emp_uniforms(m, shape):
+    """0, 1 - 2**-24, every k/m in float32 and its two float32 neighbours,
+    then random uniforms, in `shape`."""
+    k = (np.arange(m + 1) / m).astype(np.float32)
+    edges = np.concatenate([[0.0, 1 - 2**-24], k, np.nextafter(k, 2.0), np.nextafter(k, -1.0)])
+    edges = edges[(edges >= 0) & (edges < 1)].astype(np.float32)
+    size = int(np.prod(shape))
+    assert edges.size <= size
+    rest = np.asarray(jax.random.uniform(jax.random.PRNGKey(m), (size - edges.size,)))
+    return jnp.asarray(np.concatenate([edges, rest]).reshape(shape))
+
+
+def _bits(z):
+    return np.asarray(z, np.float32).view(np.int32)
+
+
+EMP_SIZES = [1, 127, 128, 129, 488, 1026, 2048, Empirical.LANE_GATHER_MAX]
+
+
+@pytest.mark.parametrize("shape", [(3, 8192 + 5), (1026, 77)], ids=str)
+@pytest.mark.parametrize("m", EMP_SIZES)
+def test_lane_gather_is_the_gather_bit_for_bit(m, shape):
+    xs = jnp.sort(jax.random.exponential(jax.random.PRNGKey(m + 1), (m,)))
+    u = _emp_uniforms(m, shape)
+    out = Empirical.lane_gather(xs, u)  # interpreted off the TPU
+    assert out.shape == u.shape
+    assert np.array_equal(_bits(out), _bits(_emp_gather(xs, u)))
+
+
+@pytest.mark.parametrize("in_axes", [(None, 0), (0, 0)], ids=["table_shared", "table_batched"])
+def test_lane_gather_under_vmap(in_axes):
+    xs = jnp.sort(jax.random.exponential(KEY, (4, 1026)), axis=-1)
+    u = _emp_uniforms(1026, (4, 1030, 3))
+    table = xs[0] if in_axes[0] is None else xs
+    lane = jax.vmap(Empirical.lane_gather, in_axes)
+    assert np.array_equal(_bits(lane(table, u)), _bits(jax.vmap(_emp_gather, in_axes)(table, u)))
+
+
+def _cpu_hlo(fn, *args):
+    """Optimized HLO of `fn` on the CPU, without the module's name and the
+    metadata and stack-frame tables that name the traced functions."""
+    text = jax.jit(fn).lower(*args).compile().as_text()
+    text = re.sub(r", metadata=\{[^}]*\}", "", text)
+    frames = re.compile(r"^(HloModule |\d+ |FileNames|FunctionNames|FileLocations|StackFrames)")
+    return "\n".join(line for line in text.splitlines() if not frames.match(line))
+
+
+@pytest.mark.parametrize("m", [488, 1026, Empirical.LANE_GATHER_MAX + 1])
+def test_emp_quantile_keeps_the_gather_on_cpu(m):
+    xs = jnp.sort(jax.random.exponential(KEY, (m,)))
+    u = jax.random.uniform(KEY, (3, 1026))
+    hlo = _cpu_hlo(emp_quantile, xs, u)
+    assert "gather(" in hlo and "custom-call" not in hlo
+    assert hlo == _cpu_hlo(_emp_gather, xs, u)
+    assert np.array_equal(_bits(emp_quantile(xs, u)), _bits(_emp_gather(xs, u)))
