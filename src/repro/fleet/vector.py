@@ -61,6 +61,7 @@ from __future__ import annotations
 
 import dataclasses
 import functools
+import math
 from functools import partial
 from typing import Optional, Sequence
 
@@ -519,35 +520,45 @@ def masked_single_fork(x_sorted, fresh, k, r, keep):
 def retry_draws(key, quantile, shape, attempts: int):
     """Shared-CRN draw pair for the geometric-retry transform.
 
-    Returns (x: shape+(attempts,), v: shape+(attempts-1,)): per logical
-    draw, `attempts` candidate service times through the inverse transform
-    and `attempts-1` fate uniforms.  The draws carry no q — a whole
-    (λ × q × π) grid shares ONE pair and each cell applies
-    `retry_transform` with its own traced q, which is exactly the
-    common-random-numbers structure the fused frontier needs: the argmin
-    over cells compares the same failure fates at different q thresholds.
+    Returns (x: (attempts, shape[-1]) + shape[:-1], v: (attempts-1,
+    shape[-1]) + shape[:-1]): per logical draw of `shape`, `attempts`
+    candidate service times through the inverse transform and `attempts-1`
+    fate uniforms, numbered row-major over shape + (attempts,) and shape +
+    (attempts-1,) as `quantile_draws` numbers them.  The attempts axis and
+    the draw's last axis lead: on a TPU the two minor axes of an array are
+    tiled by (8, 128), so trailing axes of r_cap copies and 8 attempts
+    would pad the array 21 times over.  The draws carry no q — a whole
+    (λ × q × π) grid shares ONE pair and each q applies `retry_transform`
+    with its own traced q, which is exactly the common-random-numbers
+    structure the fused frontier needs: the argmin over cells compares the
+    same failure fates at different q thresholds.
     """
+    *outer, last = shape
+    size = math.prod(shape)
     ku, kv = jax.random.split(key)
-    x = quantile_draws(ku, quantile, shape + (attempts,))
-    v = jax.random.uniform(kv, shape + (attempts - 1,))
-    return x, v
+    u = jax.random.uniform(ku, (size * attempts,)).reshape(-1, last, attempts)
+    x = quantile(u.transpose(2, 1, 0).reshape((attempts, last, *outer)))
+    v = jax.random.uniform(kv, (size * (attempts - 1),)).reshape(-1, last, attempts - 1)
+    return x, v.transpose(2, 1, 0).reshape((attempts - 1, last, *outer))
 
 
 def retry_transform(x, v, q):
-    """Effective busy time of a copy under the q failure law (traced q).
+    """Effective busy time of a copy under the q failure law (traced q),
+    in the draw's own `shape`, from `retry_draws`' pair.
 
-    Attempt k+1 runs iff attempts 1..k all failed (v[..., k-1] < q each),
-    so alive = cumprod(v < q) and the effective duration is the geometric
-    sum x[..., 0] + Σ_k alive_k · x[..., k+1].  With immediate relaunch
-    (backoff_base == 0) this IS the copy's slot busy time, so the result
-    feeds `masked_single_fork` / `lowered_policy_eval` unchanged and both
-    T and C (Definition 2 bills every attempt's wall-clock) stay exact
-    against the event engine.  The final attempt is deemed successful —
-    a truncation bias of order q**(attempts-1), negligible at the default
-    max_attempts=8.  attempts=1 degenerates to x[..., 0] (no retries).
+    Attempt k+1 runs iff attempts 1..k all failed (v[k-1] < q each), so
+    alive = cumprod(v < q) and the effective duration is the geometric sum
+    x[0] + Σ_k alive_k · x[k+1].  With immediate relaunch (backoff_base ==
+    0) this IS the copy's slot busy time, so the result feeds
+    `masked_single_fork` / `lowered_policy_eval` unchanged and both T and C
+    (Definition 2 bills every attempt's wall-clock) stay exact against the
+    event engine.  The final attempt is deemed successful — a truncation
+    bias of order q**(attempts-1), negligible at the default
+    max_attempts=8.  attempts=1 degenerates to x[0] (no retries).
     """
-    alive = jnp.cumprod((v < q).astype(x.dtype), axis=-1)
-    return x[..., 0] + jnp.sum(alive * x[..., 1:], axis=-1)
+    alive = jnp.cumprod((v < q).astype(x.dtype), axis=0)
+    busy = x[0] + jnp.sum(alive * x[1:], axis=0)
+    return jnp.moveaxis(busy, 0, -1)
 
 
 def fork_draws(key, quantile, shape, n: int, r_cap: int):
@@ -582,12 +593,13 @@ _FRONTIER_JIT_KEYS = (
 @partial(
     jax.jit,
     static_argnames=(
-        "dist", "n", "n_jobs", "m_trials", "r_cap", "n_stages", "kernel", "hist",
+        "dist", "n", "n_jobs", "m_trials", "r_cap", "n_stages", "kernel", "attempts",
+        "hist",
     ),
 )
 def _frontier_jit(
-    key, xs, modes, ks, ts, rs, keeps, ds, lams, speeds, slot_class, class_slots,
-    dist, n, n_jobs, m_trials, r_cap, n_stages, kernel, hist=None,
+    key, xs, modes, ks, ts, rs, keeps, ds, lams, qs, speeds, slot_class, class_slots,
+    dist, n, n_jobs, m_trials, r_cap, n_stages, kernel, attempts=None, hist=None,
 ):
     """Evaluate EVERY (policy, λ) cell on one shared set of random draws.
 
@@ -608,10 +620,59 @@ def _frontier_jit(
     floats), the program accumulates fixed-size γ-bucket sojourn AND cost
     bincounts in-program and ships (cells × (2·n_bins + 6)) scalars — the
     device-side observability path for large sweeps.
+
+    `qs` (None, or the Q failure probabilities of a q axis, traced) puts
+    every cell under the q task-failure law with immediate relaunch: the
+    program evaluates the (policy, λ) cells of the arguments at each q, Q ·
+    len(lams) cells in all, q fastest.  Every copy's `attempts` (static)
+    attempts and their fates are drawn once (`retry_draws`); the retry
+    transform, the sort of the originals and the fresh copies' running
+    minimum are formed once per q and shared by that q's cells.  The
+    transform needs effective duration == slot busy time, which only holds
+    for immediate relaunch — `frontier` rejects backoff_base != 0 before
+    dispatch.  qs=None traces the fault-free program.
     """
     ka, kf = jax.random.split(key)
     quantile = dist.quantile if dist is not None else partial(emp_quantile, xs)
-    if modes is None:
+    if qs is not None:
+        kx, ky = jax.random.split(kf)
+        expo_cum = jnp.cumsum(jax.random.exponential(ka, (m_trials, n_jobs)), axis=1)
+        xr, xv = retry_draws(kx, quantile, (m_trials, n_jobs, n), attempts)
+        if modes is None:
+            fr, fv = retry_draws(ky, quantile, (m_trials, n_jobs, n, r_cap), attempts)
+
+            def tc(x_sorted, fresh, k, r, keep, lam):
+                T, C = masked_single_fork(x_sorted, fresh, k, r, keep)
+                return expo_cum / lam, T, C
+
+            def per_q(q):
+                # the cells' vmap leaves these unbatched: masked_single_fork
+                # takes the running minimum of `fresh` once for all of them
+                x_sorted = jnp.sort(retry_transform(xr, xv, q), axis=-1)
+                fresh = retry_transform(fr, fv, q)
+                return jax.vmap(partial(tc, x_sorted, fresh))(ks, rs, keeps, lams)
+
+        else:
+            fr, fv = retry_draws(
+                ky, quantile, (m_trials, n_jobs, n_stages, n, r_cap), attempts
+            )
+
+            def tc(x, fresh, mode, k, t, r, keep, d, lam):
+                T, C = lowered_policy_eval(x, fresh, mode, k, t, r, keep, d)
+                return expo_cum / lam, T, C
+
+            def per_q(q):
+                x = retry_transform(xr, xv, q)
+                fresh = retry_transform(fr, fv, q)
+                return jax.vmap(partial(tc, x, fresh))(modes, ks, ts, rs, keeps, ds, lams)
+
+        # (Q, cells, m, J) -> (cells · Q, m, J): frontier's order, q fastest
+        arrivals, T, C = (
+            jnp.swapaxes(z, 0, 1).reshape((-1,) + z.shape[2:])
+            for z in jax.vmap(per_q)(qs)
+        )
+        lams = jnp.repeat(lams, qs.shape[0])
+    elif modes is None:
         # the whole grid lowered into the single-stage-quantile/full-width
         # domain (every SingleForkPolicy grid does): trace the HISTORICAL
         # program verbatim — identical HLO means identical floats, which is
@@ -708,119 +769,6 @@ def _frontier_jit(
     return jax.vmap(cellstats)(arrivals, starts, fins, slots, svc, T, C, lams)
 
 
-@partial(
-    jax.jit,
-    static_argnames=(
-        "dist", "n", "n_jobs", "m_trials", "r_cap", "n_stages", "attempts",
-        "kernel", "hist",
-    ),
-)
-def _frontier_faulty_jit(
-    key, xs, modes, ks, ts, rs, keeps, ds, lams, qs, speeds, slot_class,
-    class_slots, dist, n, n_jobs, m_trials, r_cap, n_stages, attempts, kernel,
-    hist=None,
-):
-    """`_frontier_jit` under the q task-failure law: every draw goes through
-    the geometric-retry transform with the CELL's traced q before entering
-    the policy evaluator, so a (λ × q × π) grid is still one device program
-    on one shared draw set.  The queue/stats tail below deliberately
-    DUPLICATES `_frontier_jit`'s — sharing a helper would re-fuse the
-    no-fault program and risk the bit-identity contract the bench gate pins
-    (fault=None never routes here; `_eval_cells` selects host-side).
-
-    The transform needs effective duration == slot busy time, which only
-    holds for immediate relaunch — `frontier` rejects backoff_base != 0
-    before dispatch.  attempts (static: draw-shape width) is the shared
-    max_attempts of the grid's FaultSpecs.
-    """
-    ka, kf = jax.random.split(key)
-    quantile = dist.quantile if dist is not None else partial(emp_quantile, xs)
-    kx, ky = jax.random.split(kf)
-    expo_cum = jnp.cumsum(jax.random.exponential(ka, (m_trials, n_jobs)), axis=1)
-    if modes is None:
-        xr, xv = retry_draws(kx, quantile, (m_trials, n_jobs, n), attempts)
-        fr, fv = retry_draws(ky, quantile, (m_trials, n_jobs, n, r_cap), attempts)
-
-        def tc(k, r, keep, lam, q):
-            x_sorted = jnp.sort(retry_transform(xr, xv, q), axis=-1)
-            fresh = retry_transform(fr, fv, q)
-            T, C = masked_single_fork(x_sorted, fresh, k, r, keep)
-            return expo_cum / lam, T, C
-
-        arrivals, T, C = jax.vmap(tc)(ks, rs, keeps, lams, qs)  # each (cells, m, J)
-    else:
-        xr, xv = retry_draws(kx, quantile, (m_trials, n_jobs, n), attempts)
-        fr, fv = retry_draws(
-            ky, quantile, (m_trials, n_jobs, n_stages, n, r_cap), attempts
-        )
-
-        def tc(mode, k, t, r, keep, d, lam, q):
-            x = retry_transform(xr, xv, q)
-            fresh = retry_transform(fr, fv, q)
-            T, C = lowered_policy_eval(x, fresh, mode, k, t, r, keep, d)
-            return expo_cum / lam, T, C
-
-        # each (cells, m, J)
-        arrivals, T, C = jax.vmap(tc)(modes, ks, ts, rs, keeps, ds, lams, qs)
-
-    c = speeds.shape[0]
-    starts, fins, svc, slots = batched_queue(arrivals, T, speeds, kernel=kernel)
-
-    n_classes = class_slots.shape[0]
-
-    def cellstats(a, st, fi, sl, sv, Tc, Cc, lam):
-        soj = fi - a
-        wait = st - a
-        cost = Cc / speeds[sl]
-        makespan = jnp.max(fi, axis=1) - a[:, 0]  # per trial
-        denom = jnp.maximum(makespan, 1e-12)
-        busy = cost * n  # copy-seconds per job (Definition 2)
-        total_busy = jnp.sum(busy, axis=1)  # per trial
-        util = jnp.mean(total_busy / (c * n * denom))
-
-        if c == 1:  # static: one slot, one class — no segment reductions
-            class_util = jnp.mean(total_busy[:, None] / (class_slots * denom[:, None]), axis=0)
-        else:
-
-            def trial_class_util(b_row, sl_row, dn):
-                slot_busy = jax.ops.segment_sum(b_row, sl_row, num_segments=c)
-                class_busy = jax.ops.segment_sum(
-                    slot_busy, slot_class, num_segments=n_classes
-                )
-                return class_busy / (class_slots * dn)
-
-            class_util = jnp.mean(jax.vmap(trial_class_util)(busy, sl, denom), axis=0)
-        per_trial = jnp.mean(soj, axis=1)
-        m = per_trial.shape[0]
-        rho_work = lam * jnp.mean(Cc) / jnp.sum(speeds)
-        rho_block = lam * jnp.mean(Tc) / jnp.sum(speeds)
-        base = jnp.stack(
-            [
-                jnp.mean(soj),
-                jnp.mean(wait),
-                jnp.mean(sv),
-                jnp.mean(cost),
-                util,
-                jnp.std(per_trial) / jnp.sqrt(max(m - 1, 1)),
-                jnp.maximum(rho_work, rho_block),
-                rho_work,
-                rho_block,
-            ]
-        )
-        if hist is None:
-            return jnp.concatenate([base, class_util]), soj
-        from repro.obs.device import device_histogram
-
-        s_counts, s_min, s_max, s_sum = device_histogram(soj, hist)
-        c_counts, c_min, c_max, c_sum = device_histogram(cost, hist)
-        return jnp.concatenate([base, class_util]), (
-            s_counts, jnp.stack([s_min, s_max, s_sum]),
-            c_counts, jnp.stack([c_min, c_max, c_sum]),
-        )
-
-    return jax.vmap(cellstats)(arrivals, starts, fins, slots, svc, T, C, lams)
-
-
 def as_quantile_source(dist_or_samples):
     """Normalize the frontier's first argument: (static_dist | None, xs).
 
@@ -848,18 +796,30 @@ def cell_bucket(n_cells: int) -> int:
     return b
 
 
+def _cells_per_q(n_cells: int, n_q: int, pad_cells: bool) -> int:
+    """Program cells at each q for `n_cells` (policy, λ) cells on a q axis
+    of `n_q` values: with `pad_cells`, the fewest that fill the whole grid's
+    `cell_bucket` (n_q = 1 for a grid without the axis)."""
+    if not pad_cells:
+        return n_cells
+    return -(-cell_bucket(n_cells * n_q) // n_q)
+
+
 def _cells_call(
     dist_or_samples, cell_policies, cell_lams, n, n_jobs, m_trials, key, c,
-    classes, kernel, r_cap, pad_cells, tail, cell_qs, attempts,
+    classes, kernel, r_cap, pad_cells, tail, qs, attempts,
 ):
-    """Validate one grid and lower it onto the fused program: returns the
-    jitted program `_eval_cells` dispatches, its positional arguments, its
-    `hist` keyword (the tail spec the rows are read back with) and the
-    class names — None for a grid without slot classes."""
+    """Validate one grid and lower it onto `_frontier_jit`: returns its
+    positional and keyword arguments and the class names — None for a grid
+    without slot classes.  `qs` (with the static draw width `attempts`) is
+    the q axis: the grid's cells are then the (policy, λ) cells at each q,
+    q fastest."""
     if not cell_policies:
         raise ValueError("need at least one candidate policy")
     if any(lam <= 0 for lam in cell_lams):
         raise ValueError("arrival rate lam must be > 0")
+    if qs is not None and (attempts is None or attempts < 1):
+        raise ValueError("a q axis needs a static attempts >= 1")
     if key is None:
         key = jax.random.PRNGKey(0)
     dist, xs = as_quantile_source(dist_or_samples)
@@ -867,7 +827,7 @@ def _cells_call(
     speeds, slot_class, class_slots, names = slot if slot is not None else _c1_slot_arrays(n)
 
     n_cells = len(cell_policies)
-    n_padded = cell_bucket(n_cells) if pad_cells else n_cells
+    n_padded = _cells_per_q(n_cells, 1 if qs is None else len(qs), pad_cells)
     # lower the (padded) grid to the canonical fixed-width param tensor:
     # the fork indices, wall-clock triggers, replica counts and group
     # widths all derive from the one rounding contract in core.policy
@@ -886,13 +846,6 @@ def _cells_call(
         raise ValueError(f"r_cap={r_cap} < r_max+1={r_max + 1}")
     lams = [float(lam) for lam in cell_lams]
     lams.extend([lams[0]] * (n_padded - n_cells))
-    if cell_qs is not None:
-        if len(cell_qs) != n_cells:
-            raise ValueError("need one q per cell")
-        if attempts is None or attempts < 1:
-            raise ValueError("cell_qs needs a static attempts >= 1")
-        qs = [float(q) for q in cell_qs]
-        qs.extend([qs[0]] * (n_padded - n_cells))
 
     from repro.obs.device import HistSpec, DEFAULT_HIST
 
@@ -921,19 +874,15 @@ def _cells_call(
             None, jnp.asarray(lowered.k[:, 0]), None,
             jnp.asarray(lowered.r[:, 0]), jnp.asarray(lowered.keep[:, 0]), None,
         )
-    names = names if slot is not None else None
-    if cell_qs is None:
-        args = (
-            key, xs, *pol_args, jnp.array(lams), speeds, slot_class, class_slots,
-            dist, n, n_jobs, m_trials, r_cap, lowered.n_stages, kernel,
-        )
-        return _frontier_jit, args, hist, names
     args = (
-        key, xs, *pol_args, jnp.array(lams), jnp.array(qs), speeds, slot_class,
-        class_slots, dist, n, n_jobs, m_trials, r_cap, lowered.n_stages, attempts,
-        kernel,
+        key, xs, *pol_args, jnp.array(lams), None if qs is None else jnp.array(qs),
+        speeds, slot_class, class_slots,
     )
-    return _frontier_faulty_jit, args, hist, names
+    kwargs = dict(
+        dist=dist, n=n, n_jobs=n_jobs, m_trials=m_trials, r_cap=r_cap,
+        n_stages=lowered.n_stages, kernel=kernel, attempts=attempts, hist=hist,
+    )
+    return args, kwargs, names if slot is not None else None
 
 
 def _eval_cells(
@@ -950,15 +899,15 @@ def _eval_cells(
     r_cap: Optional[int],
     pad_cells: bool,
     tail="exact",
-    cell_qs: Optional[Sequence[float]] = None,
+    qs: Optional[Sequence[float]] = None,
     attempts: Optional[int] = None,
 ) -> list[dict]:
     """Shared engine behind `frontier` and `policy_search`: one stats dict
     per (policy, λ) cell, computed by a single `_frontier_jit` dispatch.
-    `cell_qs` (one per cell, with the static draw width `attempts`) routes
-    the grid through `_frontier_faulty_jit` instead — the q failure law via
-    the geometric-retry transform; cell_qs=None never touches the faulty
-    program, preserving the historical engine's bit-identity.
+    `qs` (with the static draw width `attempts`) is a q axis: each cell is
+    evaluated at every q, q fastest, under the q failure law via the
+    geometric-retry transform, and rows gain a "q" key; qs=None runs the
+    fault-free program.
 
     `tail` selects how the percentile keys are computed: "exact" pulls the
     full sojourn matrices host-side (np.partition semantics, bit-exact);
@@ -967,36 +916,46 @@ def _eval_cells(
     accuracy guarantee, the off-device transfer is fixed-size per cell,
     and rows additionally get cost_p50/cost_p99/cost_p999."""
     with host_span("grid.lower"):
-        fn, args, hist, names = _cells_call(
+        args, kwargs, names = _cells_call(
             dist_or_samples, cell_policies, cell_lams, n, n_jobs, m_trials, key, c,
-            classes, kernel, r_cap, pad_cells, tail, cell_qs, attempts,
+            classes, kernel, r_cap, pad_cells, tail, qs, attempts,
         )
-    n_cells = len(cell_policies)
+    n_q = 1 if qs is None else len(qs)
+    n_cells = len(cell_policies) * n_q
+    span_args = dict(cells=n_cells, padded=_cells_per_q(len(cell_policies), n_q, pad_cells) * n_q)
     rec = get_recorder()
     if rec.enabled:
         # re-trace detection (obs.retrace): the padded-grid contract promises
         # that re-plans inside one geometry never recompile — observe it by
         # watching the jit cache across the dispatch
-        cache_before = jit_cache_size(fn)
-    with host_span("grid.dispatch", cells=n_cells,
-                   padded=cell_bucket(n_cells) if pad_cells else n_cells):
-        stats, payload = fn(*args, hist=hist)
+        cache_before = jit_cache_size(_frontier_jit)
+    if qs is not None:
+        span_args.update(qs=n_q, attempts=attempts)
+        # the uniforms the failure law draws: every attempt of the n
+        # originals and of each stage's r_cap fresh copies, and the fates
+        # of all attempts but the last
+        rec.count("fault.retry_draws", m_trials * n_jobs * n
+                  * (1 + kwargs["n_stages"] * kwargs["r_cap"]) * (2 * attempts - 1))
+    with host_span("grid.dispatch", **span_args):
+        stats, payload = _frontier_jit(*args, **kwargs)
     if rec.enabled:
-        cache_after = jit_cache_size(fn)
+        cache_after = jit_cache_size(_frontier_jit)
         if cache_before is not None and cache_after is not None and cache_after > cache_before:
             rec.count("obs.retrace", cache_after - cache_before)
+    hist = kwargs["hist"]
     with host_span("grid.fetch"):
         stats, payload = _fetch_grid(stats, payload, hist, n_cells)
     with host_span("grid.tail"):
         pcts, cost_pcts, cell_evt = _grid_tails(payload, hist, n_cells)
         rows = []
         nk = len(_FRONTIER_JIT_KEYS)
-        for i, (pol, lam) in enumerate(zip(cell_policies, cell_lams)):
+        for i in range(n_cells):
+            pol, lam = cell_policies[i // n_q], cell_lams[i // n_q]
             row = stats[i]
             d = dict(lam=float(lam), policy=pol.label(),
                      **dict(zip(_FRONTIER_JIT_KEYS, map(float, row[:nk]))))
-            if cell_qs is not None:
-                d["q"] = float(cell_qs[i])
+            if qs is not None:
+                d["q"] = float(qs[i % n_q])
             d["p50"], d["p99"], d["p999"] = (float(pcts[j, i]) for j in range(3))
             if cost_pcts is not None:
                 d["cost_p50"], d["cost_p99"], d["cost_p999"] = (
@@ -1092,6 +1051,18 @@ def _fault_qs(fault):
     return qs, attempts
 
 
+def _q_axis(fault):
+    """(qs, attempts) of `fault` for `_frontier_jit`'s q axis: (None, None)
+    for no fault and for a single disabled spec (q=0, no machine faults),
+    which take the fault-free program."""
+    if fault is None:
+        return None, None
+    qs, attempts = _fault_qs(fault)
+    if qs == [0.0]:
+        return None, None
+    return qs, attempts
+
+
 def frontier(
     dist_or_samples,
     policies: Sequence,
@@ -1138,35 +1109,23 @@ def frontier(
     failure-law axis: cells = policies × λs × faults (q fastest), every
     draw goes through the geometric-retry transform with its cell's q, and
     rows gain a "q" key.  A single disabled spec (q=0, no machine faults)
-    takes the exact historical program, so the rows are bitwise identical
-    to fault=None (the reduction `bench_fleet` gates).
+    takes the fault-free program, so the rows are bitwise identical to
+    fault=None (the reduction `bench_fleet` gates).
     """
     policies = list(policies)
     lams = [float(lam) for lam in lams]
     if not lams:
         raise ValueError("need at least one arrival rate")
-    cell_policies = [pol for pol in policies for _ in lams]
-    cell_lams = lams * len(policies)
-    cell_qs = attempts = None
-    if fault is not None:
-        qs, attempts = _fault_qs(fault)
-        if len(qs) == 1 and qs[0] == 0.0:
-            # disabled spec: exact historical program, bitwise-equal rows
-            rows = _eval_cells(
-                dist_or_samples, cell_policies, cell_lams, n, n_jobs, m_trials,
-                key, c, classes, kernel, r_cap, pad_cells, tail=tail,
-            )
-            for row in rows:
-                row["q"] = 0.0
-            return rows
-        cell_policies = [pol for pol in cell_policies for _ in qs]
-        cell_lams = [lam for lam in cell_lams for _ in qs]
-        cell_qs = qs * (len(policies) * len(lams))
-    return _eval_cells(
-        dist_or_samples, cell_policies, cell_lams, n, n_jobs, m_trials, key,
-        c, classes, kernel, r_cap, pad_cells, tail=tail,
-        cell_qs=cell_qs, attempts=attempts,
+    qs, attempts = _q_axis(fault)
+    rows = _eval_cells(
+        dist_or_samples, [pol for pol in policies for _ in lams], lams * len(policies),
+        n, n_jobs, m_trials, key, c, classes, kernel, r_cap, pad_cells, tail=tail,
+        qs=qs, attempts=attempts,
     )
+    if fault is not None and qs is None:
+        for row in rows:
+            row["q"] = 0.0
+    return rows
 
 
 def lower_frontier(
@@ -1183,18 +1142,21 @@ def lower_frontier(
     r_cap: Optional[int] = None,
     pad_cells: bool = True,
     tail="exact",
+    fault=None,
 ):
-    """The fault-free device program `frontier` runs for these arguments,
-    lowered but not run (a `jax.stages.Lowered`).  `.compile()` gives its
-    memory analysis and optimized HLO, and a later `frontier` (or, at one
-    λ, `policy_search`) call of the same shapes reuses that executable."""
+    """The device program `frontier` runs for these arguments, lowered but
+    not run (a `jax.stages.Lowered`).  `.compile()` gives its memory
+    analysis and optimized HLO, and a later `frontier` (or, at one λ and
+    one q, `policy_search`) call of the same shapes reuses that
+    executable."""
     lams = [float(lam) for lam in lams]
-    fn, args, hist, _ = _cells_call(
+    qs, attempts = _q_axis(fault)
+    args, kwargs, _ = _cells_call(
         dist_or_samples, [pol for pol in policies for _ in lams],
         lams * len(policies), n, n_jobs, m_trials, key, c, classes, kernel,
-        r_cap, pad_cells, tail, None, None,
+        r_cap, pad_cells, tail, qs, attempts,
     )
-    return fn.lower(*args, hist=hist)
+    return _frontier_jit.lower(*args, **kwargs)
 
 
 def sweep(
@@ -1309,19 +1271,13 @@ def policy_search(
     if lam <= 0:
         raise ValueError("arrival rate lam must be > 0")
     candidates = list(candidates)
-    cell_qs = attempts = None
-    if fault is not None:
-        qs, attempts = _fault_qs(fault)
-        if len(qs) != 1:
-            raise ValueError("policy_search takes a single FaultSpec")
-        if qs[0] == 0.0:
-            cell_qs = attempts = None  # disabled: exact historical program
-        else:
-            cell_qs = qs * len(candidates)
+    qs, attempts = _q_axis(fault)
+    if qs is not None and len(qs) != 1:
+        raise ValueError("policy_search takes a single FaultSpec")
     rows = _eval_cells(
         samples, candidates, [float(lam)] * len(candidates), n, n_jobs, m_trials,
         key, c, classes, kernel, r_cap, pad_candidates, tail=tail,
-        cell_qs=cell_qs, attempts=attempts,
+        qs=qs, attempts=attempts,
     )
     out = []
     for pol, row in zip(candidates, rows):

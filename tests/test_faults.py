@@ -348,14 +348,76 @@ def test_retry_transform_limits_and_monotonicity():
 
     x, v = vector.retry_draws(jax.random.PRNGKey(0), DIST.quantile,
                               (64, 16), attempts=6)
+    # attempts and the draw's last axis lead; the transform gives the shape
+    assert x.shape == (6, 16, 64) and v.shape == (5, 16, 64)
     base = vector.retry_transform(x, v, 0.0)
-    np.testing.assert_array_equal(np.asarray(base), np.asarray(x[..., 0]))
+    np.testing.assert_array_equal(np.asarray(base), np.asarray(x[0].T))
     full = vector.retry_transform(x, v, 1.0)
-    np.testing.assert_allclose(np.asarray(full), np.asarray(jnp.sum(x, axis=-1)),
+    np.testing.assert_allclose(np.asarray(full), np.asarray(jnp.sum(x, axis=0).T),
                                rtol=1e-6)
     means = [float(jnp.mean(vector.retry_transform(x, v, q)))
              for q in (0.0, 0.2, 0.5, 0.8)]
     assert all(b > a for a, b in zip(means, means[1:]))  # E[total] grows with q
+
+
+# ------------------------------------------ fused engines: the q axis
+
+
+@pytest.mark.parametrize(
+    "n_pols,lams,qs",
+    [
+        (3, (0.05, 0.2), (0.2,)),  # one q: 6 cells padded to 8
+        (2, (0.05, 0.2), (0.1, 0.3)),  # two q interleaved, 8 cells, no padding
+        (3, (0.1,), (0.05, 0.25)),  # 6 cells padded to 8 (4 a q)
+        (2, (0.1,), (0.0, 0.1, 0.3)),  # 6 cells padded to 9 (3 a q)
+    ],
+    ids=["one_q", "two_q", "pads", "three_q"],
+)
+def test_q_axis_rows_match_per_cell_evaluation(n_pols, lams, qs):
+    """The q axis forms each q's effective draws once and evaluates that
+    q's cells against them; a one-cell grid draws the same numbers (the key
+    layout does not depend on the grid), so every row of the grid equals
+    the row of its cell evaluated alone, bit for bit: the cells of a q
+    share its arrays, and no reduction runs across cells."""
+    import jax
+
+    pols = [POL, SingleForkPolicy(0.3, 2, False), SingleForkPolicy(0.0, 0, True)][:n_pols]
+    key = jax.random.PRNGKey(13)
+    rows = vector.frontier(DIST, pols, lams, n=8, n_jobs=64, m_trials=4, key=key, c=2,
+                           r_cap=3, fault=[FaultSpec(q=q) for q in qs])
+    assert len(rows) == len(pols) * len(lams) * len(qs)
+    it = iter(rows)
+    for pol in pols:
+        for lam in lams:
+            for q in qs:
+                # a one-cell q axis, q = 0 included (frontier would take a
+                # lone disabled spec to the fault-free program)
+                (alone,) = vector._eval_cells(DIST, [pol], [lam], 8, 64, 4, key, 2, None,
+                                              False, 3, True, qs=[q], attempts=8)
+                assert next(it) == alone
+
+
+def test_lower_frontier_lowers_the_failure_program():
+    """lower_frontier(fault=...) hands out the program frontier(fault=...)
+    dispatches: once it is compiled, the call compiles nothing more."""
+    import jax
+
+    compiles = []
+
+    def listener(name, duration, **kwargs):
+        if name == "/jax/core/compile/backend_compile_duration":
+            compiles.append(duration)
+
+    pols, lams = [POL, SingleForkPolicy(0.3, 2, False)], (0.05, 0.2)
+    kw = dict(m_trials=4, c=2, r_cap=3, fault=[FaultSpec(q=0.1), FaultSpec(q=0.2)])
+    compiled = vector.lower_frontier(DIST, pols, lams, 8, 48, **kw).compile()
+    assert compiled.memory_analysis().temp_size_in_bytes > 0
+    jax.monitoring.register_event_duration_secs_listener(listener)
+    try:
+        rows = vector.frontier(DIST, pols, lams, 8, 48, **kw)
+    finally:
+        jax.monitoring.unregister_event_duration_listener(listener)
+    assert compiles == [] and [r["q"] for r in rows[:2]] == [0.1, 0.2]
 
 
 # ------------------------------------ fused vs event oracle (5σ agreement)

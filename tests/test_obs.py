@@ -410,6 +410,46 @@ def test_frontier_emits_dispatch_span_when_enabled():
     assert spans[0].args == {"cells": 1, "padded": 8}
 
 
+@pytest.mark.parametrize(
+    "search,span_args",
+    [
+        (False, {"cells": 8, "padded": 8, "qs": 2, "attempts": 4}),
+        (True, {"cells": 3, "padded": 8, "qs": 1, "attempts": 4}),
+    ],
+    ids=["frontier", "policy_search"],
+)
+def test_fault_grid_dispatch_args_and_retry_draw_counter(search, span_args):
+    """A grid on the q axis names its q count and attempt cap on
+    `grid.dispatch`, and `fault.retry_draws` counts the uniforms its
+    failure law draws a call: every attempt of the n originals and r_cap
+    fresh copies, and the fates of all attempts but the last."""
+    from repro.core import ShiftedExp, SingleForkPolicy
+    from repro.faults import FaultSpec
+    from repro.fleet import vector
+
+    pols = (SingleForkPolicy(0.0, 0, True), SingleForkPolicy(0.25, 1, False),
+            SingleForkPolicy(0.25, 2, True))
+    law = ShiftedExp(1.0, 1.0)
+    kw = dict(n_jobs=32, m_trials=4, r_cap=3)
+    rec = obs_trace.enable()
+    try:
+        vector.frontier(law, pols[:2], (0.1,), 8, **kw)  # fault-free: counts nothing
+        for _ in range(2):
+            if search:
+                vector.policy_search(law, pols, 0.1, 8, fault=FaultSpec(q=0.1, max_attempts=4),
+                                     **kw)
+            else:
+                vector.frontier(law, pols[:2], (0.1, 0.2), 8,
+                                fault=[FaultSpec(q=q, max_attempts=4) for q in (0.1, 0.2)],
+                                **kw)
+    finally:
+        obs_trace.disable()
+    spans = rec.spans_named("grid.dispatch")
+    assert spans[0].args == {"cells": 2, "padded": 8}
+    assert [s.args for s in spans[1:]] == [span_args] * 2
+    assert rec.counters["fault.retry_draws"] == 2 * 4 * 32 * 8 * (1 + 3) * (2 * 4 - 1)
+
+
 GRID_SPANS = ("grid.lower", "grid.dispatch", "grid.fetch", "grid.tail")
 
 

@@ -81,10 +81,10 @@ def test_lane_gather_compiles_for_v5e(one_chip, m, shape):
 
 
 def _grid_hlo(one_chip, dist_or_samples, policies, lams, n, n_jobs, m_trials, c, qs=None):
-    """The optimized HLO of the fused frontier program (`_frontier_jit`, or
-    `_frontier_faulty_jit` with one q a cell and 8 attempts) for a grid,
-    compiled for a described v5e."""
-    fn, args, hist, _ = vector._cells_call(
+    """The optimized HLO of the fused frontier program (`_frontier_jit`,
+    with a q axis of 8 attempts where `qs` is given) for a grid, compiled
+    for a described v5e."""
+    args, kwargs, _ = vector._cells_call(
         dist_or_samples, policies, lams, n, n_jobs, m_trials, None, c, None, False, None,
         True, "exact", qs, None if qs is None else 8,
     )
@@ -93,7 +93,7 @@ def _grid_hlo(one_chip, dist_or_samples, policies, lams, n, n_jobs, m_trials, c,
         if isinstance(a, jax.Array) else a
         for a in args
     ]
-    return fn.lower(*args, hist=hist).compile().as_text()
+    return vector._frontier_jit.lower(*args, **kwargs).compile().as_text()
 
 
 def test_frontier_program_looks_up_empirical_draws_in_vmem_on_v5e(one_chip):
@@ -115,16 +115,16 @@ def test_other_grid_programs_look_up_empirical_draws_in_vmem_on_v5e(one_chip, gr
     """The programs the benchmark's cells do not run take the kernel too, at
     n = 1026 with 16 trials of 64 jobs and 32 cells: a grid with wall-clock
     relaunches (`policy_draws`, the general evaluator) and a q failure grid
-    (`_frontier_faulty_jit`, 8 attempts a draw: as many uniforms as a cell's
-    16 x 512 jobs without failures)."""
+    (8 cells at each of 4 q, 8 attempts a draw: as many uniforms as a
+    cell's 16 x 512 jobs without failures)."""
     single = [SingleForkPolicy(p, 1, keep) for p in (0.02, 0.05) for keep in (True, False)]
-    if grid == "relaunch":
-        policies, qs = single + [delayed_relaunch(t, 1) for t in (1.0, 1.5, 2.0, 3.0)], None
-    else:
-        policies, qs = single * 2, [0.05, 0.1, 0.2, 0.1] * 8
     trace = jnp.sort(jax.random.exponential(jax.random.PRNGKey(0), (1026,)))
-    hlo = _grid_hlo(one_chip, trace, [p for p in policies for _ in range(4)],
-                    [0.05, 0.1, 0.15, 0.2] * 8, 1026, 64, 16, 4, qs)
+    if grid == "relaunch":
+        policies = single + [delayed_relaunch(t, 1) for t in (1.0, 1.5, 2.0, 3.0)]
+        cells, lams, qs = [p for p in policies for _ in range(4)], [0.05, 0.1, 0.15, 0.2] * 8, None
+    else:
+        cells, lams, qs = [p for p in single for _ in range(2)], [0.05, 0.1] * 4, [0.02, 0.05, 0.1, 0.2]
+    hlo = _grid_hlo(one_chip, trace, cells, lams, 1026, 64, 16, 4, qs)
     assert 'custom_call_target="tpu_custom_call"' in hlo
     assert "emp_quantile" in hlo
 
