@@ -8,15 +8,20 @@ jobs: job1's n = 1026 tasks per job, c = 4 gang blocks (4104 task slots) in
 two machine classes, and a grid of 8 policies x 4 arrival rates.  Task
 times bootstrap the synthesized Fig. 7 traces, made from a fixed seed.
 
-  (a) `frontier` with the Kiefer-Wolfowitz queue as an XLA scan and as the
-      Pallas `kw_queue` kernel, on one key: the rows must agree;
+  (a) `frontier` with `kernel=False` and `kernel=True`: on a TPU both run
+      the Kiefer-Wolfowitz queue (c = 4) as the Pallas `kw_queue` kernel,
+      so both programs must hold it; then the kernel (`batched_queue`) on
+      one cell's queue inputs against `kw_queue_ref`, the scan, on the
+      host;
   (b) `policy_search` at one arrival rate, twice: the second call must not
       compile again;
   (c) `trace_kill_rollout`, whose straggler residuals run through the
       Pallas `residual_sample` kernel, against `residual_sample_ref` on the
       same uniforms;
-  (d) `dag_frontier` on a map (n = 1026) -> reduce (n = 488) DAG with both
-      queue realizations, which must agree;
+  (d) `dag_frontier` on a map (n = 1026) -> reduce (n = 488) DAG with
+      `kernel=False` and `kernel=True`, both holding the kernel; then the
+      kernel against `kw_queue_ref` on the queue inputs of one reduce-stage
+      cell (c = 2 blocks of one speed);
   (e) at n = 16, c = 3, 200 jobs, `frontier` against the event engine
       (`FleetSim`, aligned placement), within 5 sigma.
 
@@ -59,7 +64,7 @@ R_CAP = 3
 #: arrival rates: the baseline policy's gang-block occupancy on the
 #: two-class fleet (block speeds 1, 1, 0.7, 0.7) runs from about 0.2 to 0.8
 LAMS = (0.05, 0.1, 0.15, 0.2)
-#: relative agreement of the two queue realizations on the same draws
+#: relative agreement of the queue kernel and its reference on the same inputs
 RTOL = 1e-4
 
 
@@ -73,30 +78,67 @@ def has_kernel(compiled) -> bool:
     return "tpu_custom_call" in compiled.as_text()
 
 
+def has_queue_kernel(compiled) -> bool:
+    """The compiled program runs the Pallas `kw_queue` kernel."""
+    import re
+
+    return re.search(r'%kw_queue(\.\d+)? = [^\n]*"tpu_custom_call"', compiled.as_text()) is not None
+
+
 def peak_bytes(dev) -> int:
     """The device's peak allocation so far in this process."""
     return dev.memory_stats()["peak_bytes_in_use"]
 
 
-def rows_agree(a: list[dict], b: list[dict]) -> float:
-    """Largest relative difference over the numeric keys of two row lists
-    (a difference below RTOL of the row's mean sojourn counts as none, so
-    near-zero waits do not dominate); raises if they disagree."""
+def queue_vs_ref(samples, n: int, speeds, pol, lam: float, sz: Sizes, key) -> str:
+    """The queue kernel on one grid cell's queue inputs against
+    `kw_queue_ref` (the per-queue scan) run on the host's CPU.
+
+    The inputs are drawn as the fused frontier draws a cell's: arrivals at
+    rate `lam`, and each job's service under `pol` from the originals and
+    fresh copies of `samples` (n tasks, R_CAP draws a copy).  The program
+    `batched_queue` lowers for the chip must hold the kernel; starts,
+    finishes and stretched services must agree within RTOL of the cell's
+    mean sojourn.  Returns a summary for the phase's line."""
+    import functools
+
+    import jax
+    import jax.numpy as jnp
     import numpy as np
 
-    worst = 0.0
-    check(len(a) == len(b), "row counts differ")
-    for ra, rb in zip(a, b):
-        scale = abs(ra["mean_sojourn"])
-        for k, va in ra.items():
-            if not isinstance(va, float):
-                continue
-            vb = rb[k]
-            check(np.isfinite(va) and np.isfinite(vb), f"{k} not finite")
-            err = abs(va - vb) / max(abs(va), abs(vb), scale)
-            worst = max(worst, err)
-    check(worst <= RTOL, f"rows differ by {worst:.3e} > {RTOL}")
-    return worst
+    from repro.core.distributions import Empirical
+    from repro.core.policy import num_stragglers
+    from repro.fleet import vector
+    from repro.kernels.ref import kw_queue_ref
+
+    @jax.jit
+    def inputs(key, xs):
+        ka, kf = jax.random.split(key)
+        quantile = functools.partial(vector.emp_quantile, xs)
+        x_sorted, fresh = vector.fork_draws(kf, quantile, (sz.m_trials, sz.n_jobs), n, R_CAP)
+        arrivals = jnp.cumsum(jax.random.exponential(ka, (sz.m_trials, sz.n_jobs)), axis=1) / lam
+        T, _ = vector.masked_single_fork(
+            x_sorted, fresh, n - num_stragglers(n, pol.p), pol.r, pol.keep)
+        return arrivals, T
+
+    arrivals, T = inputs(key, Empirical(samples).sorted)
+    speeds = jnp.asarray(speeds, jnp.float32)
+    compiled = jax.jit(vector.batched_queue).lower(arrivals, T, speeds).compile()
+    check(has_queue_kernel(compiled), "batched_queue: no kw_queue kernel in the compiled HLO")
+    got = [np.asarray(z) for z in compiled(arrivals, T, speeds)]
+    cpu = jax.devices("cpu")[0]
+    host = [jax.device_put(np.asarray(z), cpu) for z in (arrivals, T, speeds)]
+    with jax.default_device(cpu):
+        want = [np.asarray(z) for z in kw_queue_ref(*host)]
+    a = np.asarray(arrivals)
+    scale = float(np.mean(want[1] - a))
+    worst = max(float(np.max(np.abs(g - w))) for g, w in zip(got[:3], want[:3])) / scale
+    for z in got[:3]:
+        check(bool(np.all(np.isfinite(z))), "queue kernel: non-finite output")
+    check(worst <= RTOL, f"queue kernel vs kw_queue_ref: {worst:.3e} > {RTOL}")
+    slots_differ = int(np.sum(got[3] != want[3]))
+    return (f"kernel vs kw_queue_ref over {a.size} jobs on {speeds.shape[0]} slots: "
+            f"max difference {worst:.3e} of the mean sojourn, slots differing {slots_differ}")
 
 
 def main() -> None:
@@ -185,7 +227,7 @@ def run(sz: Sizes) -> None:
         compiled = lower().compile()
         return compiled, time.perf_counter() - t0
 
-    # (a) frontier: the KW queue as an XLA scan and as the Pallas kernel
+    # (a) frontier: on a TPU both settings of kernel= run the KW queue kernel
     rows = {}
     for kernel in (False, True):
         compiled, compile_s = compile_(lambda: lower_frontier(
@@ -194,12 +236,11 @@ def run(sz: Sizes) -> None:
                                 kernel=kernel, **grid)
         _, first_s = timed(call)
         rows[kernel], wall_s = timed(call)
-        report(f"a.frontier kernel={kernel}", compiled, compile_s, first_s, wall_s, kernel)
-    worst = rows_agree(rows[False], rows[True])
-    best = min(rows[True], key=lambda r: r["mean_sojourn"])
-    print(f"[a] kernel vs scan: max relative difference {worst:.3e} over "
-          f"{len(rows[True])} rows; lowest mean sojourn {best['mean_sojourn']:.4f} "
-          f"({best['policy']}, lam={best['lam']})")
+        report(f"a.frontier kernel={kernel}", compiled, compile_s, first_s, wall_s, True)
+        check(has_queue_kernel(compiled), f"a.frontier kernel={kernel}: no kw_queue kernel")
+    best = min(rows[False], key=lambda r: r["mean_sojourn"])
+    print(f"[a] {queue_vs_ref(samples, n, [1.0, 1.0, 0.7, 0.7], policies[3], LAMS[2], sz, key)}; "
+          f"lowest mean sojourn {best['mean_sojourn']:.4f} ({best['policy']}, lam={best['lam']})")
 
     # (b) the controller's re-plan: one λ, the same shapes twice
     lam = LAMS[2]
@@ -255,7 +296,7 @@ def run(sz: Sizes) -> None:
            f"s={s} r+1={r1} mean_sojourn={res.mean_sojourn:.4f}")
     del res, x_sorted, u
 
-    # (d) a map -> reduce DAG with both queue realizations
+    # (d) a map -> reduce DAG: both settings of kernel= run the kernel per stage
     dag = JobDAG([
         StageSpec("map", n, samples, c=c),
         StageSpec("reduce", sz.n_reduce, load_stage_trace("reduce"), c=2, deps=("map",)),
@@ -266,17 +307,16 @@ def run(sz: Sizes) -> None:
         for pr in (SingleForkPolicy(0.0, 0, True), SingleForkPolicy(0.05, 1, True))
     ]
     dgrid = dict(m_trials=sz.m_trials, key=key, r_caps=(R_CAP, R_CAP))
-    dag_rows = {}
     for kernel in (False, True):
         compiled, compile_s = compile_(lambda: lower_dag_frontier(
             dag, vectors, LAMS, sz.n_jobs, kernel=kernel, **dgrid))
         call = lambda: dag_frontier(dag, vectors, LAMS, sz.n_jobs, kernel=kernel, **dgrid)  # noqa: E731
         _, first_s = timed(call)
-        dag_rows[kernel], wall_s = timed(call)
-        report(f"d.dag_frontier kernel={kernel}", compiled, compile_s, first_s, wall_s, kernel)
-    worst = rows_agree(dag_rows[False], dag_rows[True])
-    print(f"[d] kernel vs scan: max relative difference {worst:.3e} over "
-          f"{len(dag_rows[True])} rows")
+        _, wall_s = timed(call)
+        report(f"d.dag_frontier kernel={kernel}", compiled, compile_s, first_s, wall_s, True)
+        check(has_queue_kernel(compiled), f"d.dag_frontier kernel={kernel}: no kw_queue kernel")
+    reduce = load_stage_trace("reduce")
+    print(f"[d] {queue_vs_ref(reduce, sz.n_reduce, [1.0, 1.0], vectors[1][1], LAMS[3], sz, key)}")
 
     # (e) small enough for the event engine: the chip's numbers are right,
     # not merely self-consistent

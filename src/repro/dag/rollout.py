@@ -18,9 +18,10 @@ The engine composes the fused frontier machinery stage by stage:
     so a whole joint-policy search is a single device program and
     same-grid comparisons are variance-reduced;
   * stage queues run through the shared `fleet.vector.batched_queue` cell
-    engine — closed-form Lindley at c = 1, the Kiefer–Wolfowitz scan at
-    c > 1, or (`kernel=True`) the Pallas `kernels.kw_queue` kernel with
-    (cells × trials) rows tiled across its grid, one call per stage;
+    engine — closed-form Lindley at c = 1; at c > 1 the Pallas
+    `kernels.kw_queue` kernel with (cells × trials) rows tiled across its
+    grid, one call per stage, on a TPU (and with `kernel=True` anywhere),
+    else the Kiefer–Wolfowitz scan;
   * barrier-release times of a downstream stage need not be monotone (a
     c > 1 upstream queue can complete jobs out of order), so each stage
     sorts jobs by release time, runs the FIFO recursion, and inverts the
@@ -54,6 +55,7 @@ import numpy as np
 from repro.core.policy import SingleForkPolicy, lower_policies, max_replicas
 from repro.core.simulate import lowered_policy_eval, policy_draws
 from repro.fleet.vector import (
+    _dispatch_queue,
     _fault_qs,
     _fetch_grid,
     _grid_tails,
@@ -471,7 +473,8 @@ def _eval_dag_cells(
         )
     n_cells = len(cell_vectors)
     with host_span("grid.dispatch", cells=n_cells,
-                   padded=cell_bucket(n_cells) if pad_cells else n_cells):
+                   padded=cell_bucket(n_cells) if pad_cells else n_cells,
+                   queue=_dispatch_queue(args[0], [s.c for s in dag.stages], kernel)):
         stats, payload = _dag_stats_jit(*args, **kwargs)
     with host_span("grid.fetch"):
         stats, payload = _fetch_grid(stats, payload, hist, n_cells)
@@ -531,8 +534,10 @@ def dag_frontier(
     One compilation covers any same-shaped grid: (k, r, keep) per stage and
     λ are traced per-cell vectors, cells pad to power-of-two buckets, and
     `r_caps` pins per-stage fresh-draw widths for re-plan stability.
-    `kernel=True` routes every stage's queue through the Pallas
-    `kernels.kw_queue` kernel (one call per stage).
+    On a TPU every stage queue of c > 1 runs through the Pallas
+    `kernels.kw_queue` kernel, one call per stage (`batched_queue`), and
+    elsewhere as a `lax.scan`; `kernel=True` takes the kernel on every
+    platform and for every c.
 
     `fault` (a `repro.faults.FaultSpec` or sequence — q law, immediate
     relaunch only) adds a failure axis exactly as in the fleet `frontier`:

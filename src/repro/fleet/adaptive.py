@@ -22,9 +22,9 @@ load-dependent (Aktaş et al., "Which Clones Should Attack and When?";
     variable is *fleet sojourn under estimated load*, not single-job
     latency.  Re-plans are recompile-free: the candidate grid is padded to
     a fixed bucket and the fresh-draw width is pinned to `r_max + 1`
-    (`r_cap`), so an online grid change never re-traces; `use_kernel=True`
-    additionally routes the queue recursions through the Pallas
-    `kernels.kw_queue` kernel;
+    (`r_cap`), so an online grid change never re-traces; on a TPU the
+    queue recursions of c > 1 run through the Pallas `kernels.kw_queue`
+    kernel, and `use_kernel=True` takes it on every platform;
   * candidates whose estimated ρ ≥ `rho_max` are vetoed whenever a stable
     alternative exists (the stability guard the single-job controller
     lacks);

@@ -25,12 +25,13 @@ per-job cost is C(π).  Concretely:
   * `c = 1` takes the Lindley recursion start_j = max(arrival_j,
     finish_{j-1}) in closed form (`lindley`: cumsum + cummax, no
     sequential scan at all);
-  * `c > 1` is the Kiefer–Wolfowitz multi-server recursion: either the
-    per-job `lax.scan` (`kw_queue`, vmapped over trials and cells) or —
-    behind the `kernel=True` switch on `fleet_rollout` / `policy_search` /
-    `frontier` — the Pallas kernel `repro.kernels.kw_queue`, which keeps
-    the slot free-time vector in VMEM and tiles (trials × grid-cells)
-    across the Pallas grid (interpret mode on CPU, Mosaic on TPU);
+  * `c > 1` is the Kiefer–Wolfowitz multi-server recursion: in a fused
+    program lowered for a TPU the Pallas kernel `repro.kernels.kw_queue`,
+    which keeps the slot free-time vector in VMEM and tiles (trials ×
+    grid-cells) across the Pallas grid; on any other platform the per-job
+    `lax.scan` (`kw_queue`, vmapped over trials and cells).  `kernel=True`
+    on `fleet_rollout` / `policy_search` / `frontier` forces the kernel on
+    every platform (interpret mode off a TPU);
   * heterogeneous machine classes (`workload.MachineClass`) enter as
     per-slot speed multipliers: a job served by a speed-v slot stretches
     its whole sample path by 1/v — T, C and the slot's busy time all scale
@@ -206,7 +207,7 @@ def kw_queue(arrivals, services, speeds):
 
     This is the `lax.scan` realization; `repro.kernels.kw_queue` is the
     same recursion as a Pallas kernel over batches of independent queues
-    (the `kernel=True` path of the rollout/search/frontier entry points).
+    (what `batched_queue` runs on a TPU, and `kernel=True` everywhere).
 
     Returns (starts, finishes, scaled_services, slots), each (n_jobs,).
     """
@@ -443,32 +444,68 @@ def emp_quantile(xs, u):
     return jax.lax.platform_dependent(xs, u, tpu=Empirical.lane_gather, default=gather)
 
 
+def _dispatch_queue(key, cs, kernel: bool) -> str:
+    """The `queue` arg of a fused engine's `grid.dispatch` span: the queue
+    recursion `batched_queue` runs for each stage's c, "/"-joined in stage
+    order — "kw_kernel" (the Pallas `kernels.kw_queue`), "kw_scan" (the
+    vmapped `lax.scan`) or "lindley" (the closed form at c = 1) — read
+    from the same observables: c, the default float dtype the queues run
+    in, and the platform of the device `key`, the call's first argument,
+    is on."""
+    if isinstance(key, jax.Array):
+        platform = next(iter(key.devices())).platform
+    else:
+        platform = jax.default_backend()
+    on_tpu = platform == "tpu" and _kernel_takes(jax.dtypes.canonicalize_dtype(float))
+    return "/".join(
+        "kw_kernel" if kernel or (c > 1 and on_tpu) else "lindley" if c == 1 else "kw_scan"
+        for c in cs
+    )
+
+
+def _kernel_takes(dtype) -> bool:
+    """Mosaic runs the queue kernel on float32 rows only (no float64)."""
+    return jnp.dtype(dtype) == jnp.float32
+
+
 def batched_queue(arrivals, services, speeds, kernel: bool = False):
     """FIFO G/G/c queues over an arbitrary batch: the one cell engine every
     stage of a composed rollout routes through.
 
     `arrivals` / `services` are (..., n_jobs) with any shared leading batch
     shape (trials, grid cells, both); each row is one independent queue with
-    `speeds.shape[0]` job slots.  Three realizations, selected exactly as in
-    the fused frontier: `kernel=True` flattens the batch into rows of ONE
-    Pallas `kernels.kw_queue` call; c = 1 is the closed-form Lindley
-    recursion (no sequential scan); c > 1 is the vmapped Kiefer–Wolfowitz
-    `lax.scan`.  Returns (starts, finishes, scaled_services, slots), each
-    with the input shape.  Rows must be sorted by arrival (FIFO order) —
-    stage-composed callers sort by barrier-release time first and invert
-    the permutation afterwards (`repro.dag.rollout`).
+    `speeds.shape[0]` job slots.  The realization follows from c, the
+    dtype and the platform the program is lowered for: c = 1 is the
+    closed-form Lindley recursion (no sequential scan); c > 1 with float32
+    rows flattens the batch into rows of ONE Pallas `kernels.kw_queue`
+    call in a program lowered for a TPU (`lax.platform_dependent`), and is
+    the vmapped Kiefer–Wolfowitz `lax.scan` on any other platform or
+    dtype.  `kernel=True` takes the kernel on every platform and for every
+    c (interpreted off a TPU).  Returns (starts, finishes,
+    scaled_services, slots), each with the input shape.  Rows must be
+    sorted by arrival (FIFO order) — stage-composed callers sort by
+    barrier-release time first and invert the permutation afterwards
+    (`repro.dag.rollout`).
     """
     batch = arrivals.shape[:-1]
     J = arrivals.shape[-1]
     c = speeds.shape[0]
     flat = lambda z: z.reshape((-1, J))  # noqa: E731
     unflat = lambda z: z.reshape(batch + (J,))  # noqa: E731
-    if kernel:
-        # one Pallas call: every batch row tiled across the kernel grid
-        from repro.kernels.kw_queue import kw_queue as kw_queue_pallas
 
-        outs = kw_queue_pallas(flat(arrivals), flat(services), speeds)
+    def pallas(arrivals, services, speeds):
+        # one Pallas call: every batch row tiled across the kernel grid
+        from repro.kernels.kw_queue import kw_queue_rows
+
+        outs = kw_queue_rows(flat(arrivals), flat(services), speeds)
         return tuple(unflat(z) for z in outs)
+
+    def scan(arrivals, services, speeds):
+        outs = jax.vmap(lambda a, t: kw_queue(a, t, speeds))(flat(arrivals), flat(services))
+        return tuple(unflat(z) for z in outs)
+
+    if kernel:
+        return pallas(arrivals, services, speeds)
     if c == 1:
         svc = services / speeds[0]
         starts, fins = jax.vmap(lindley)(flat(arrivals), flat(svc))
@@ -478,8 +515,9 @@ def batched_queue(arrivals, services, speeds, kernel: bool = False):
             svc,
             jnp.zeros(arrivals.shape, jnp.int32),
         )
-    outs = jax.vmap(lambda a, t: kw_queue(a, t, speeds))(flat(arrivals), flat(services))
-    return tuple(unflat(z) for z in outs)
+    if not (_kernel_takes(arrivals.dtype) and _kernel_takes(services.dtype)):
+        return scan(arrivals, services, speeds)
+    return jax.lax.platform_dependent(arrivals, services, speeds, tpu=pallas, default=scan)
 
 
 def masked_single_fork(x_sorted, fresh, k, r, keep):
@@ -922,7 +960,11 @@ def _eval_cells(
         )
     n_q = 1 if qs is None else len(qs)
     n_cells = len(cell_policies) * n_q
-    span_args = dict(cells=n_cells, padded=_cells_per_q(len(cell_policies), n_q, pad_cells) * n_q)
+    span_args = dict(
+        cells=n_cells, padded=_cells_per_q(len(cell_policies), n_q, pad_cells) * n_q,
+        # args end (speeds, slot_class, class_slots): c is the slot count
+        queue=_dispatch_queue(args[0], [args[-3].shape[0]], kernel),
+    )
     rec = get_recorder()
     if rec.enabled:
         # re-trace detection (obs.retrace): the padded-grid contract promises
@@ -1100,8 +1142,10 @@ def frontier(
     power-of-two buckets (`pad_cells`), and `r_cap` pins the fresh-draw
     width (pass the largest r you will ever search, e.g. the adaptive
     controller's `r_max + 1`).
-    `kernel=True` routes the queue recursions through the Pallas
-    `kernels.kw_queue` kernel, (trials × cells) tiled across its grid.
+    The queue recursions of c > 1 run through the Pallas `kernels.kw_queue`
+    kernel, (trials × cells) tiled across its grid, in a program lowered
+    for a TPU, and as a `lax.scan` elsewhere (`batched_queue`);
+    `kernel=True` takes the kernel on every platform and for every c.
     `tail="hist"` computes the percentile keys from in-program γ-bucket
     histograms instead of the raw sojourn matrices (see `_eval_cells`).
 
@@ -1252,8 +1296,9 @@ def policy_search(
     candidates is far sharper than independent rollouts of equal size), and
     with `pad_candidates` (power-of-two cell buckets) plus a pinned `r_cap`
     an online re-plan never recompiles as the candidate set flexes.
-    `kernel=True` runs the queue recursions through the Pallas
-    `kernels.kw_queue` kernel.
+    On a TPU the queue recursions of c > 1 run through the Pallas
+    `kernels.kw_queue` kernel (`batched_queue`); `kernel=True` takes it on
+    every platform and for every c.
 
     Returns one dict per candidate: the policy itself, its label, mean
     sojourn/wait/service/cost, utilization, percentile sojourns, and

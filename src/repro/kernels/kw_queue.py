@@ -18,6 +18,11 @@ the Pallas grid.  Layout:
   * slot selection is branch-free min/where reductions over the c slots
     (the sublane axis of the free-time tile): no gather, no argmin.
 
+`repro.fleet.vector.batched_queue` runs it for every c > 1 float32 queue
+batch of a program lowered for a TPU; the body is traced under
+`repro.kernels.without_callers`, so a program holding it gets one
+compile-cache key from any caller.
+
 Semantics are identical to `repro.fleet.vector.kw_queue` (the lax.scan
 reference): job j takes the lowest-indexed slot already idle at its
 arrival — slots are ordered fastest first — else the earliest-freeing
@@ -34,7 +39,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels import run_kernel
+from repro.kernels import run_kernel, without_callers
 
 #: queues per grid step (the lane width)
 BLOCK_B = 128
@@ -92,7 +97,7 @@ def _call(a, s, sp, *, block_j, interpret):
     (Jp, Bp), c = a.shape, sp.shape[0]
     tile = pl.BlockSpec((block_j, BLOCK_B), lambda i, j: (j, i))
     return pl.pallas_call(
-        functools.partial(_kernel, c=c),
+        without_callers(functools.partial(_kernel, c=c)),
         grid=(Bp // BLOCK_B, Jp // block_j),
         in_specs=[tile, tile, pl.BlockSpec((c, BLOCK_B), lambda i, j: (0, 0))],
         out_specs=[tile] * 4,
@@ -103,14 +108,17 @@ def _call(a, s, sp, *, block_j, interpret):
             dimension_semantics=("parallel", "arbitrary"),
         ),
         interpret=interpret,
+        name="kw_queue",
     )(a, s, sp)
 
 
-@jax.jit
-def kw_queue(arrivals, services, speeds):
+def kw_queue_rows(arrivals, services, speeds):
     """arrivals, services: (n_queues, n_jobs) independent FIFO queues;
     speeds: (c,) per-slot speed multipliers, sorted descending.
-    Returns (starts, finishes, scaled_services, slots), each (n_queues, n_jobs)."""
+    Returns (starts, finishes, scaled_services, slots), each (n_queues, n_jobs).
+
+    Traced into its caller's program with no jit boundary, so the kernel's
+    operation carries the caller's source location (`batched_queue`'s)."""
     B, J = arrivals.shape
     c = speeds.shape[0]
     block_j = min(BLOCK_J, -(-J // _ROWS) * _ROWS)
@@ -122,3 +130,7 @@ def kw_queue(arrivals, services, speeds):
     sp = jnp.broadcast_to(speeds.astype(a.dtype)[:, None], (c, BLOCK_B))
     outs = run_kernel(functools.partial(_call, block_j=block_j), a, s, sp)
     return tuple(z[:J, :B].T for z in outs)
+
+
+#: `kw_queue_rows` as a program of its own
+kw_queue = jax.jit(kw_queue_rows)

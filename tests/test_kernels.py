@@ -118,17 +118,42 @@ def test_kw_queue_kernel_matches_ref(B, J, c):
     assert np.array_equal(np.asarray(outs_k[3]), np.asarray(outs_r[3]))  # slots
 
 
-def test_kw_queue_kernel_matches_fleet_scan():
-    """And ≡ the fleet fast path's own scan (`vector.kw_queue`), per queue."""
+def _cell_batch():
+    """A cell-like batch: 3 cells x 4 trials x 100 jobs on 4 slots in two
+    speed classes.  Jobs 0-47 come in bursts of 12 at one instant (all
+    slots idle at a burst, none idle after its first 4; equal services
+    leave exact ties in the slots' free times), jobs 48-99 one at a time,
+    spaced wider than any service (every slot idle).  The cells scale the
+    clock; trials 0 and 1 have equal services, 2 and 3 random ones."""
+    j = np.arange(100)
+    clock = np.where(j < 48, (j // 12) * 50.0, 200.0 + (j - 48) * 20.0)
+    arr = np.asarray([1.0, 0.5, 0.25])[:, None, None] * clock
+    arr = np.broadcast_to(arr, (3, 4, 100))
+    rand = 0.5 + np.asarray(jax.random.exponential(jax.random.PRNGKey(7), (3, 2, 100)))
+    svc = np.concatenate([np.full((3, 1, 100), 2.0), np.full((3, 1, 100), 1.0), rand], axis=1)
+    speeds = np.asarray([1.0, 1.0, 0.7, 0.7])
+    return tuple(jnp.asarray(z, jnp.float32) for z in (arr, svc, speeds))
+
+
+@pytest.mark.parametrize("case", ["random", "cell_batch"])
+def test_kw_queue_kernel_matches_fleet_scan(case):
+    """And ≡ the fleet fast path's own scan (`vector.kw_queue`, vmapped
+    over the batch), through `batched_queue`: the kernel (`kernel=True`)
+    against the default realization on a CPU, bit for bit; "cell_batch"
+    holds exact ties and runs of all-idle and none-idle jobs."""
     from repro.fleet import vector as fleet_vector
 
-    arr, svc, speeds = _kw_inputs(6, 50, 3, seed=5)
-    outs_k = ops.kw_queue(arr, svc, speeds)
-    for i in range(arr.shape[0]):
-        outs_s = fleet_vector.kw_queue(arr[i], svc[i], speeds)
-        for a, b in zip(outs_k[:3], outs_s[:3]):
-            np.testing.assert_allclose(np.asarray(a[i]), np.asarray(b), rtol=1e-5, atol=1e-5)
-        assert np.array_equal(np.asarray(outs_k[3][i]), np.asarray(outs_s[3]))
+    arr, svc, speeds = _kw_inputs(6, 50, 3, seed=5) if case == "random" else _cell_batch()
+    outs_k = fleet_vector.batched_queue(arr, svc, speeds, kernel=True)
+    outs_s = fleet_vector.batched_queue(arr, svc, speeds)
+    flat = arr.reshape(-1, arr.shape[-1]), svc.reshape(-1, arr.shape[-1])
+    for i in range(flat[0].shape[0]):  # the default is the scan on every row
+        row = fleet_vector.kw_queue(flat[0][i], flat[1][i], speeds)
+        for a, b in zip(outs_s, row):
+            np.testing.assert_array_equal(np.asarray(a).reshape(-1, a.shape[-1])[i], np.asarray(b))
+    for a, b in zip(outs_k, outs_s):
+        assert a.shape == arr.shape and a.dtype == b.dtype
+        np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
 
 
 def test_kw_queue_kernel_heterogeneous_speeds_scale_service():

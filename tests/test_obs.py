@@ -407,14 +407,14 @@ def test_frontier_emits_dispatch_span_when_enabled():
         obs_trace.disable()
     spans = rec.spans_named("grid.dispatch")
     assert len(spans) == 1 and spans[0].pid == obs_trace.PID_PROFILER
-    assert spans[0].args == {"cells": 1, "padded": 8}
+    assert spans[0].args == {"cells": 1, "padded": 8, "queue": "lindley"}
 
 
 @pytest.mark.parametrize(
     "search,span_args",
     [
-        (False, {"cells": 8, "padded": 8, "qs": 2, "attempts": 4}),
-        (True, {"cells": 3, "padded": 8, "qs": 1, "attempts": 4}),
+        (False, {"cells": 8, "padded": 8, "queue": "lindley", "qs": 2, "attempts": 4}),
+        (True, {"cells": 3, "padded": 8, "queue": "lindley", "qs": 1, "attempts": 4}),
     ],
     ids=["frontier", "policy_search"],
 )
@@ -445,7 +445,7 @@ def test_fault_grid_dispatch_args_and_retry_draw_counter(search, span_args):
     finally:
         obs_trace.disable()
     spans = rec.spans_named("grid.dispatch")
-    assert spans[0].args == {"cells": 2, "padded": 8}
+    assert spans[0].args == {"cells": 2, "padded": 8, "queue": "lindley"}
     assert [s.args for s in spans[1:]] == [span_args] * 2
     assert rec.counters["fault.retry_draws"] == 2 * 4 * 32 * 8 * (1 + 3) * (2 * 4 - 1)
 
@@ -508,7 +508,62 @@ def test_grid_spans_reach_the_recorder(engine):
                     if s.name in GRID_SPANS), key=lambda sp: sp[1])
     _in_order_without_overlap(spans)
     assert all(s.pid == obs_trace.PID_PROFILER for s in rec.spans)
-    assert rec.spans_named("grid.dispatch")[0].args == {"cells": 4, "padded": 8}
+    queue = "lindley" if engine == "fleet" else "lindley/lindley"  # one value per stage
+    assert rec.spans_named("grid.dispatch")[0].args == {"cells": 4, "padded": 8, "queue": queue}
+
+
+@pytest.mark.parametrize(
+    "engine,c,kernel,queue",
+    [
+        ("fleet", 2, False, "kw_scan"),
+        ("fleet", 2, True, "kw_kernel"),
+        ("fleet", 1, True, "kw_kernel"),
+        ("dag", (4, 2), False, "kw_scan/kw_scan"),
+        ("dag", (1, 2), True, "kw_kernel/kw_kernel"),
+    ],
+    ids=["fleet_scan", "fleet_kernel", "fleet_c1_kernel", "dag_scan", "dag_kernel"],
+)
+def test_dispatch_span_names_the_queue_realization(engine, c, kernel, queue):
+    """`grid.dispatch` names the queue recursion the call ran: on a CPU
+    the scan for c > 1 and Lindley at c = 1, and the kernel wherever
+    `kernel=True` forces it."""
+    import jax
+
+    from repro.core import ShiftedExp, SingleForkPolicy
+
+    pol = SingleForkPolicy(0.2, 1, False)
+    kw = dict(n_jobs=32, m_trials=2, key=jax.random.PRNGKey(5), kernel=kernel)
+    rec = obs_trace.enable()
+    try:
+        if engine == "fleet":
+            from repro.fleet import frontier
+
+            frontier(ShiftedExp(1.0, 1.0), (pol,), (0.1,), 8, c=c, **kw)
+        else:
+            from repro.dag import JobDAG, StageSpec, dag_frontier
+
+            dag = JobDAG([
+                StageSpec("map", 8, ShiftedExp(1.0, 1.0), c=c[0]),
+                StageSpec("reduce", 4, ShiftedExp(1.0, 0.5), c=c[1], deps=("map",)),
+            ])
+            dag_frontier(dag, [(pol, pol)], (0.1,), **kw)
+    finally:
+        obs_trace.disable()
+    assert rec.spans_named("grid.dispatch")[0].args["queue"] == queue
+
+
+def test_dispatch_queue_on_a_tpu_is_the_kernel_for_c_above_one(monkeypatch):
+    """On a TPU (here the default backend, for a key that is no device
+    array) every stage of c > 1 runs the kernel and c = 1 keeps Lindley."""
+    import jax
+    import numpy as np
+
+    from repro.fleet import vector
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    key = np.zeros(2, np.uint32)
+    assert vector._dispatch_queue(key, [4, 2, 1], False) == "kw_kernel/kw_kernel/lindley"
+    assert vector._dispatch_queue(key, [1], True) == "kw_kernel"
 
 
 @pytest.mark.parametrize("tail", ["exact", "hist"])
